@@ -1,7 +1,8 @@
 """Paged KV decode path: ops-level paged vs contiguous decode-attention
-latency (interpret-mode Pallas on CPU), engine-level paged vs contiguous
-decode steps, and the measured offload traffic + link-priced tax of a
-pool-constrained run — the capacity half of the serving story."""
+latency (Pallas compiled on a TPU, interpreted elsewhere), engine-level
+paged vs contiguous decode steps, and the measured offload traffic +
+link-priced tax of a pool-constrained run — the capacity half of the
+serving story."""
 from __future__ import annotations
 
 import time
@@ -15,6 +16,7 @@ from repro.configs import get_config, reduced
 from repro.core.device_model import PLATFORMS, offload_cost_s
 from repro.inference.engine import Request, ServeEngine
 from repro.inference.kv_quant import quantize_kv
+from repro.kernels import pallas_interpret
 from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention)
 from repro.models import init_params
@@ -61,9 +63,11 @@ def run() -> list[str]:
     vp = v.transpose(0, 2, 1, 3).reshape(B * nb, bs, HKV, hd)
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     lens = jnp.full((B,), t, jnp.int32)
-    tc = _time(lambda: decode_attention(q, k, v, t, scale=0.2, block_kv=bs))
+    interp = pallas_interpret()
+    tc = _time(lambda: decode_attention(q, k, v, t, scale=0.2, block_kv=bs,
+                                        interpret=interp))
     tp = _time(lambda: paged_decode_attention(q, kp, vp, tables, lens,
-                                              scale=0.2))
+                                              scale=0.2, interpret=interp))
     rows.append(csv_row("paged_decode/ops_contiguous", tc * 1e6,
                         f"B={B};T={t};block_kv={bs}"))
     rows.append(csv_row("paged_decode/ops_paged", tp * 1e6,
@@ -75,7 +79,7 @@ def run() -> list[str]:
     qv, sv = quantize_kv(vp)
     tq = _time(lambda: paged_decode_attention(q, qk, qv, tables, lens,
                                               scale=0.2, k_scale=sk,
-                                              v_scale=sv))
+                                              v_scale=sv, interpret=interp))
     rows.append(csv_row("paged_decode/ops_paged_int8", tq * 1e6,
                         f"B={B};pages={B * nb};bs={bs};"
                         f"vs_paged_bf16={tq / tp:.2f}x"))

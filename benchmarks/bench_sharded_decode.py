@@ -3,14 +3,18 @@ steps on reduced smollm (byte-identical greedy tokens asserted), plus the
 modeled per-layer collective tax of a full-size decode step on LC vs CC
 coupling fabrics — the multi-GPU half of the serving story.
 
-The tp comparison runs in a subprocess with a forced host-platform device
-count (this process may hold a single device); the child prints one
-parseable line per engine and the parent re-emits benchmark rows."""
+The tp pair runs in this process on its own devices and each row names
+them.  An accelerator backend with fewer than two devices is an error,
+never a fall back to the CPU.  Only when the CPU is the backend and this
+process holds one device does the pair run in a child with virtual CPU
+devices (the child imports this module and runs the same code)."""
 from __future__ import annotations
 
+import json
+import os
 import subprocess
 import sys
-import textwrap
+from pathlib import Path
 
 from benchmarks.common import FAST, csv_row
 from repro.configs import get_config
@@ -23,57 +27,69 @@ MAX_LEN = 64
 REQUESTS = 4 if FAST else 6
 MAX_NEW = 4 if FAST else 8
 
-_CHILD = """
-import json, jax, numpy as np
-from repro.configs import get_config, reduced
-from repro.inference.engine import Request, ServeEngine
-from repro.models import init_params
 
-cfg = reduced(get_config("{arch}"), n_layers=2)
-params = init_params(jax.random.PRNGKey(0), cfg)
+def _pair_in_process() -> list[dict]:
+    """tp=1 then tp=2 engines on this process's devices."""
+    import jax
+    import numpy as np
+    from repro.configs import reduced
+    from repro.inference.engine import Request, ServeEngine
+    from repro.models import init_params
 
-def reqs():
-    rng = np.random.default_rng(0)
-    return [Request(i, prompt=list(rng.integers(0, cfg.vocab_size, 8)),
-                    max_new_tokens={max_new}) for i in range({requests})]
+    cfg = reduced(get_config(ARCH), n_layers=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    dev = jax.devices()[0]
+    device = f"{dev.platform}:{dev.device_kind}x{jax.device_count()}"
 
-def measure(tp):
-    eng = ServeEngine(cfg, params, max_batch=2, max_len={max_len}, tp=tp)
-    eng.run(reqs())                 # warmup: pay jit/shard_map compiles
-    eng.reset()
-    done = eng.run(reqs())
-    toks = [r.generated for r in sorted(done, key=lambda r: r.rid)]
-    st = eng.stats
-    steps = st.step_times_s
-    return toks, {{
-        "tp": tp,
-        "mean_step_us": 1e6 * sum(steps) / len(steps) if steps else 0.0,
-        "decode_steps": st.decode_steps,
-        "decode_dispatches": st.decode_dispatches,
-        "per_device": st.per_device_dispatches,
-        "collective_bytes_per_step": st.collective_bytes_per_decode_step,
-        "modeled_collective_tax_us": st.modeled_collective_tax_s * 1e6,
-    }}
+    def reqs():
+        rng = np.random.default_rng(0)
+        return [Request(i, prompt=list(rng.integers(0, cfg.vocab_size, 8)),
+                        max_new_tokens=MAX_NEW) for i in range(REQUESTS)]
 
-t1, r1 = measure(1)
-t2, r2 = measure(2)
-assert t1 == t2, ("tp=2 tokens diverged from tp=1", t1, t2)
-print("ROW", json.dumps(r1))
-print("ROW", json.dumps(r2))
-"""
+    def measure(tp):
+        eng = ServeEngine(cfg, params, max_batch=2, max_len=MAX_LEN, tp=tp)
+        eng.run(reqs())                 # warmup: pay jit/shard_map compiles
+        eng.reset()
+        done = eng.run(reqs())
+        toks = [r.generated for r in sorted(done, key=lambda r: r.rid)]
+        st = eng.stats
+        steps = st.step_times_s
+        return toks, {
+            "tp": tp, "device": device,
+            "mean_step_us": 1e6 * sum(steps) / len(steps) if steps else 0.0,
+            "decode_steps": st.decode_steps,
+            "decode_dispatches": st.decode_dispatches,
+            "per_device": st.per_device_dispatches,
+            "collective_bytes_per_step": st.collective_bytes_per_decode_step,
+            "modeled_collective_tax_us": st.modeled_collective_tax_s * 1e6,
+        }
+
+    t1, r1 = measure(1)
+    t2, r2 = measure(2)
+    assert t1 == t2, ("tp=2 tokens diverged from tp=1", t1, t2)
+    return [r1, r2]
 
 
 def _measure_tp_pair() -> list[dict]:
-    import json
-    import os
+    import jax
+    if jax.device_count() >= 2:
+        return _pair_in_process()
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"sharded_decode needs 2 {jax.default_backend()} devices, "
+            f"found {jax.device_count()}")
+    root = Path(__file__).resolve().parents[1]
     env = dict(os.environ)
     env.update({
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={DEVICES}",
-        "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src"})
-    code = textwrap.dedent(_CHILD).format(
-        arch=ARCH, requests=REQUESTS, max_new=MAX_NEW, max_len=MAX_LEN)
+        "JAX_PLATFORMS": "cpu",
+        "PYTHONPATH": os.pathsep.join([str(root / "src"), str(root)])})
+    code = ("import json\n"
+            "from benchmarks.bench_sharded_decode import _pair_in_process\n"
+            "for r in _pair_in_process():\n"
+            "    print('ROW', json.dumps(r))\n")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=540)
+                         text=True, env=env, cwd=root, timeout=540)
     if out.returncode != 0:
         raise RuntimeError(f"sharded child failed: {out.stderr[-2000:]}")
     return [json.loads(line.split(" ", 1)[1])
@@ -91,7 +107,7 @@ def run() -> list[str]:
             f"dispatches={r['decode_dispatches']};{per_dev};"
             f"coll_B_per_step={r['collective_bytes_per_step']:.0f};"
             f"coll_tax_us={r['modeled_collective_tax_us']:.1f};"
-            "tokens=byte-identical-vs-tp1"))
+            f"device={r['device']};tokens=byte-identical-vs-tp1"))
 
     # modeled: per-step collective tax of FULL smollm decode, LC vs CC —
     # the same per-layer psum payloads the sharded backend captures,

@@ -13,8 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
-
 
 def _quantize(x, err):
     xf = x.astype(jnp.float32) + err
@@ -29,7 +27,7 @@ def compressed_psum(grads, errors, axis: str):
 
     Call INSIDE shard_map.  Returns (mean grads f32, new error state).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         q, scale, new_e = _quantize(g, e)
@@ -69,7 +67,7 @@ def make_compressed_dp_grad(loss_fn, mesh, axis: str = "data"):
         def rep(t):
             return jax.tree.map(lambda _: P(), t)
         bspec = jax.tree.map(lambda _: P(axis), batch)
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(rep(params), rep(errors), bspec),
             out_specs=(rep(params), rep(errors), P()),

@@ -17,9 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.distributed.compat import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def pipeline_forward(stage_fn: Callable, stage_params, x_micro, mesh,
@@ -56,14 +54,17 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_micro, mesh,
         return ys[None]                                    # (1, ticks, ...)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(spec_p, P()),
         out_specs=P(axis),
         check_vma=False)
     ys = fn(stage_params, x_micro)                 # (P, ticks, mb, ...)
-    # last stage emits microbatch t at tick t + P - 1
-    return ys[n_stages - 1, n_stages - 1:]
+    # last stage emits microbatch t at tick t + P - 1; the result lives on
+    # that stage's device, so name its (replicated) sharding explicitly —
+    # an Explicit ``pipe`` axis cannot infer it from the indexing alone
+    return ys.at[n_stages - 1, n_stages - 1:].get(
+        out_sharding=NamedSharding(mesh, P()))
 
 
 def reference_forward(stage_fn: Callable, stage_params, x_micro):

@@ -101,18 +101,25 @@ class _PlannedFn:
 
 
 class LocalBackend(AccountingMixin):
-    """Default single-device backend (jit or launch-plan dispatch)."""
+    """Default single-device backend (jit or launch-plan dispatch).
+
+    Params and caches are committed to ``device`` (default: the first
+    device), so every step runs there — a replica fleet gives each
+    engine its own device this way.
+    """
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int,
                  max_len: int, plan: str = "jit",
-                 platform: str = "TPU-v5e"):
+                 platform: str = "TPU-v5e", device=None):
         self.cfg = cfg
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.B = max_batch
         self.T = max_len
         self.plan = plan
         self.platform = platform
-        self.info = BackendInfo(kind="local", tp=1, devices=(0,))
+        self.info = BackendInfo(kind="local", tp=1,
+                                devices=(self.device.id,))
         self._init_accounting()
         self._planned_prefill: dict = {}    # (bucket, plen) -> _PlannedFn
         self._planned_decode: Optional[_PlannedFn] = None
@@ -133,13 +140,17 @@ class LocalBackend(AccountingMixin):
 
     # ------------------------------------------------------------ caches
     def init_contiguous_cache(self):
-        """Fresh per-slot contiguous KV cache on the local device."""
-        return make_cache(self.cfg, self.B, self.T, src_len=1,
-                          dtype=self.cfg.cdtype)
+        """Fresh per-slot contiguous KV cache on the backend's device."""
+        with jax.default_device(self.device):
+            cache = make_cache(self.cfg, self.B, self.T, src_len=1,
+                               dtype=self.cfg.cdtype)
+        return jax.device_put(cache, self.device)
 
     def init_paged_cache(self, kv):
-        """Fresh pooled KV pages for the paged-cache layout."""
-        return kv.make_pages()
+        """Fresh pooled KV pages on the backend's device."""
+        with jax.default_device(self.device):
+            pages = kv.make_pages()
+        return jax.device_put(pages, self.device)
 
     # ------------------------------------------------------------ helpers
     def _planned_account(self, pf: _PlannedFn) -> CallAccount:

@@ -5,10 +5,9 @@ existing sharding-rule engine (``distributed.sharding.param_specs`` /
 ``cache_specs`` / ``paged_cache_specs`` + ``launch.mesh.make_host_mesh``):
 wq/wk/wv column-sharded by head, wo row-sharded, MLP d_ff split, KV caches
 (contiguous and paged) head-sharded.  The prefill/decode bodies run under
-``shard_map`` (via the version shims in ``distributed.compat``) with a
-PER-DEVICE config — ``n_heads/tp`` local heads — and the model's
-``reduce`` hook psums the partial attention/MLP outputs over the model
-axis.  Embeddings and the LM head stay replicated, so every device holds
+``jax.shard_map`` with a PER-DEVICE config — ``n_heads/tp`` local heads —
+and the model's ``reduce`` hook psums the partial attention/MLP outputs
+over the model axis.  Embeddings and the LM head stay replicated, so every device holds
 identical activations between blocks and the greedy tokens are the same
 ones the single-device ``LocalBackend`` emits.
 
@@ -33,14 +32,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.device_model import PLATFORMS, allreduce_cost_s
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import (cache_specs, paged_cache_specs,
                                         param_specs, shardings_for)
 from repro.inference.backends.base import (AccountingMixin, BackendInfo,
                                            CallAccount)
 from repro.inference.backends.bodies import make_step_bodies
 from repro.launch.mesh import make_host_mesh
-from repro.models import make_cache
+from repro.models import init_params, make_cache
 
 _SUPPORTED_KINDS = ("attn", "attn_local")
 
@@ -66,6 +64,39 @@ def _validate_tp(cfg: ModelConfig, tp: int) -> None:
                 f"shard_map body runs {dim}//tp per device (pick a tp "
                 f"from the divisors of {val}, or serve this arch with "
                 f"tp=1)")
+
+
+def _param_spec_tree(params, cfg: ModelConfig, mesh):
+    """Head-sharded param specs with embeddings + unembed replicated:
+    every device computes the full (tiny at decode) logits row, so
+    out_specs need no gather.  ``params`` may be arrays or shapes."""
+    specs = dict(param_specs(params, cfg, mesh, tp="model"))
+    specs["embed"] = P(None, None)
+    if "lm_head" in specs:
+        specs["lm_head"] = P(None, None)
+    return specs
+
+
+def _jit_into(make, spec_tree, mesh):
+    """Run ``make()`` under jit straight into ``spec_tree``'s shardings:
+    each device materializes only its own shards, so a pytree larger
+    than one device never sits whole on one device."""
+    shardings = shardings_for(jax.eval_shape(make), spec_tree, mesh)
+    return jax.jit(make, out_shardings=shardings)()
+
+
+def init_sharded_params(key, cfg: ModelConfig, tp: int):
+    """``init_params(key, cfg)`` placed in ``ShardedBackend``'s layout
+    over the first ``tp`` devices, never whole on one of them — the way
+    to build a model that only fits across the mesh."""
+    _validate_tp(cfg, tp)
+    mesh = make_host_mesh(data=1, model=tp)
+
+    def make():
+        return init_params(key, cfg)
+
+    specs = _param_spec_tree(jax.eval_shape(make), cfg, mesh)
+    return _jit_into(make, specs, mesh)
 
 
 class ShardedBackend(AccountingMixin):
@@ -100,14 +131,9 @@ class ShardedBackend(AccountingMixin):
         self.cfg_local = cfg.replace(n_heads=cfg.n_heads // tp,
                                      n_kv_heads=cfg.n_kv_heads // tp,
                                      head_dim=cfg.hd)
-        specs = param_specs(params, cfg, self.mesh, tp="model")
-        # embeddings + unembed stay replicated: every device computes the
-        # full (tiny at decode) logits row, so out_specs need no gather
-        specs = dict(specs)
-        specs["embed"] = P(None, None)
-        if "lm_head" in specs:
-            specs["lm_head"] = P(None, None)
+        specs = _param_spec_tree(params, cfg, self.mesh)
         self.param_spec_tree = specs
+        # a no-op for params from ``init_sharded_params``
         self.params = jax.device_put(
             params, shardings_for(params, specs, self.mesh))
         self._cache_spec_tree = None        # set by init_*_cache
@@ -138,26 +164,25 @@ class ShardedBackend(AccountingMixin):
 
     # ------------------------------------------------------------ caches
     def init_contiguous_cache(self):
-        """Head-sharded contiguous KV cache placed on the tp mesh."""
-        cache = make_cache(self.cfg, self.B, self.T, src_len=1,
-                           dtype=self.cfg.cdtype)
-        specs = cache_specs(cache, self.cfg, self.mesh, dp=("data",),
-                            tp="model")
-        self._cache_spec_tree = specs
-        return jax.device_put(cache,
-                              shardings_for(cache, specs, self.mesh))
+        """Head-sharded contiguous KV cache built on the tp mesh."""
+        def make():
+            return make_cache(self.cfg, self.B, self.T, src_len=1,
+                              dtype=self.cfg.cdtype)
+
+        self._cache_spec_tree = cache_specs(
+            jax.eval_shape(make), self.cfg, self.mesh, dp=("data",),
+            tp="model")
+        return _jit_into(make, self._cache_spec_tree, self.mesh)
 
     def init_paged_cache(self, kv):
-        """Head-sharded pooled KV pages placed on the tp mesh."""
-        pages = kv.make_pages()
-        specs = paged_cache_specs(pages, self.cfg, self.mesh, tp="model")
-        self._cache_spec_tree = specs
-        return jax.device_put(pages,
-                              shardings_for(pages, specs, self.mesh))
+        """Head-sharded pooled KV pages built on the tp mesh."""
+        self._cache_spec_tree = paged_cache_specs(
+            jax.eval_shape(kv.make_pages), self.cfg, self.mesh, tp="model")
+        return _jit_into(kv.make_pages, self._cache_spec_tree, self.mesh)
 
     # ------------------------------------------------------------ dispatch
     def _wrapped(self, key, body, arg_specs, logits_spec=P(None, None)):
-        """jit(shard_map(body)) for one step kind, built lazily once the
+        """jit(jax.shard_map(body)) for one step kind, built lazily once the
         cache spec tree exists (cache structure fixes in_specs)."""
         fn = self._fns.get(key)
         if fn is None:
@@ -168,7 +193,7 @@ class ShardedBackend(AccountingMixin):
             in_specs = (self.param_spec_tree, self._cache_spec_tree,
                         *arg_specs)
             out_specs = (logits_spec, self._cache_spec_tree)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False))
             self._fns[key] = fn

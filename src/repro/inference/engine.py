@@ -321,7 +321,7 @@ class ServeEngine:
                  speculative: bool = False, draft_config=None,
                  draft_params=None, spec_k: int = 4,
                  spec_inflection: Optional[int] = None, monitor=True,
-                 tracer=None):
+                 tracer=None, device=None):
         if plan not in PLAN_STRATEGIES:
             raise ValueError(f"unknown plan {plan!r}; "
                              f"expected one of {PLAN_STRATEGIES}")
@@ -408,10 +408,11 @@ class ServeEngine:
         self.cache_mode = cache
         self.prefill_chunk = prefill_chunk
         # the backend owns everything device-side (placement, meshes,
-        # compiled steps); pass backend= to serve through a custom one
+        # compiled steps); pass backend= to serve through a custom one,
+        # device= to pin a tp=1 engine to one device
         self.backend = backend if backend is not None else make_backend(
             cfg, params, max_batch=max_batch, max_len=max_len, tp=tp,
-            plan=plan, platform=platform)
+            plan=plan, platform=platform, device=device)
         self.speculative = bool(speculative)
         self.spec_k = spec_k
         self.spec_inflection = spec_inflection

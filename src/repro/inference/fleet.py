@@ -4,12 +4,12 @@ Each replica is an unmodified ``ServeEngine`` over its own execution
 backend, so everything the engine composes — launch plans, paged KV with
 offload, tensor parallelism, speculative decoding — composes with data
 parallelism for free: a fleet of R replicas at ``tp=T`` is the
-``(data=R, model=T)`` grid of ``launch.mesh.make_fleet_mesh``.  On a
-device pool that actually holds R*T devices the fleet validates that
-mesh at construction; on a smaller pool (CPU CI, local runs) replicas
-time-multiplex the local devices and the fleet runs as a
-byte-deterministic simulation — the routing, queueing, and accounting
-behavior is identical either way because the scheduler layer never
+``(data=R, model=T)`` grid of ``launch.mesh.make_fleet_mesh``.  Each
+tp=1 replica is pinned to its own device (the least-loaded one when it
+is created), so four replicas on a four-chip host hold their params and
+caches on four chips; a pool with fewer devices than replicas (a CPU
+run) puts several replicas on one device.  Routing, queueing, and
+accounting are identical either way because the scheduler layer never
 touches placement.
 
 The fleet owns replica lifecycle only (create, drain, retire, metrics
@@ -26,7 +26,10 @@ never loses or corrupts an admitted request.
 """
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, field
+
+import jax
 
 from repro.inference.engine import EngineStats, Request, ServeEngine
 from repro.telemetry.registry import MetricsRegistry
@@ -54,8 +57,8 @@ class ReplicaFleet:
     """Replica lifecycle + fleet-level metrics for one model deployment.
 
     All replicas share one config and one params pytree (data parallelism
-    replicates weights; here they alias the same host arrays), and each
-    builds its own backend/cache through the normal ``ServeEngine``
+    replicates weights: each tp=1 replica copies them to its device), and
+    each builds its own backend/cache through the normal ``ServeEngine``
     constructor — ``engine_kwargs`` forwards serving options (plan, cache
     mode, tp, ...) to every replica identically.
     """
@@ -96,9 +99,19 @@ class ReplicaFleet:
             self.add_replica()
 
     # ------------------------------------------------------------ lifecycle
+    def _next_device(self):
+        """The device holding the fewest live replicas (lowest index on
+        ties), for the next tp=1 replica."""
+        load = Counter(d for rep in self.replicas.values()
+                       for d in rep.engine.backend.info.devices)
+        return min(jax.devices(), key=lambda d: load[d.id])
+
     def _make_engine(self) -> ServeEngine:
         """One fresh replica engine (own backend, cache, registry)."""
-        return ServeEngine(self.cfg, self.params, **self.engine_kwargs)
+        kwargs = dict(self.engine_kwargs)
+        if self.tp == 1:
+            kwargs["device"] = self._next_device()
+        return ServeEngine(self.cfg, self.params, **kwargs)
 
     def add_replica(self) -> Replica:
         """Attach a new serving replica (fresh engine, next fleet rid)."""

@@ -109,8 +109,8 @@ def _paged_dec_quant_kernel(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)                    # (1, hd)
     # dequantize in-register: int8 payload (bs, hd) x f32 scale (bs, 1)
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -135,10 +135,13 @@ def _paged_dec_quant_kernel(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref,
 
 def paged_decode_attention_quant_kernel(q, k_pages, v_pages, k_scale,
                                         v_scale, block_tables, kv_lens, *,
-                                        scale, interpret=True):
+                                        scale, interpret=False):
     """q: (B,HQ,1,hd); k_pages/v_pages: (HKV,P,bs,hd) int8; k_scale/v_scale:
-    (HKV,P,bs) f32 per-(token, head) scales, DMA'd per page tile by the
-    same scalar-prefetched block table that steers the payload fetch."""
+    (HKV,P,bs,1) f32 per-(token, head) scales, DMA'd per page tile by the
+    same scalar-prefetched block table that steers the payload fetch.  The
+    trailing unit dim keeps the scale tile (bs, 1) legal under the TPU's
+    (8, 128) tiling: its last dim equals the array's, and bs is a
+    multiple of 8."""
     b, hq, _, hd = q.shape
     hkv, n_pages, bs = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
@@ -148,9 +151,9 @@ def paged_decode_attention_quant_kernel(q, k_pages, v_pages, k_scale,
     page_spec = pl.BlockSpec((1, 1, bs, hd),
                              lambda bb, h, ikv, bt, kl: (h // g, bt[bb, ikv],
                                                          0, 0))
-    scale_spec = pl.BlockSpec((1, 1, bs),
+    scale_spec = pl.BlockSpec((1, 1, bs, 1),
                               lambda bb, h, ikv, bt, kl: (h // g,
-                                                          bt[bb, ikv], 0))
+                                                          bt[bb, ikv], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # block_tables, kv_lens
         grid=(b, hq, nb),
@@ -176,7 +179,7 @@ def paged_decode_attention_quant_kernel(q, k_pages, v_pages, k_scale,
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, kv_lens,
-                                  *, scale, interpret=True):
+                                  *, scale, interpret=False):
     """q: (B,HQ,1,hd); k_pages/v_pages: (HKV,P,bs,hd) — note the head axis
     leads so each grid step DMAs one (bs,hd) page tile; block_tables:
     (B,NB) int32 page ids (scalar-prefetched: the index map reads them to
@@ -217,7 +220,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, kv_lens,
 
 
 def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv=512,
-                            interpret=True):
+                            interpret=False):
     """q: (B,HQ,1,hd); k/v: (B,HKV,T,hd); kv_len: (1,) int32."""
     b, hq, _, hd = q.shape
     hkv, t = k.shape[1], k.shape[2]

@@ -13,7 +13,7 @@ from repro.kernels.decode_attention.kernel import (
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_kv", "interpret"))
 def decode_attention(q, k, v, kv_len=None, *, scale: float, block_kv=512,
-                     interpret=True):
+                     interpret=False):
     """q: (B,HQ,hd); k/v: (B,HKV,T,hd); kv_len scalar (None -> T)."""
     b, hq, hd = q.shape
     t = k.shape[2]
@@ -41,7 +41,7 @@ def decode_attention(q, k, v, kv_len=None, *, scale: float, block_kv=512,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_lens, *,
-                           scale: float, interpret=True,
+                           scale: float, interpret=False,
                            k_scale=None, v_scale=None):
     """Decode attention through a block-table paged KV cache.
 
@@ -72,8 +72,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_lens, *,
     kv_lens = jnp.minimum(kv_lens.astype(jnp.int32),
                           block_tables.shape[1] * bs)
     if k_scale is not None:
-        ks = jnp.transpose(k_scale, (2, 0, 1)).astype(jnp.float32)
-        vs = jnp.transpose(v_scale, (2, 0, 1)).astype(jnp.float32)
+        # (P,bs,HKV) -> (HKV,P,bs,1): head-major like the payload pages
+        ks = jnp.transpose(k_scale, (2, 0, 1))[..., None].astype(jnp.float32)
+        vs = jnp.transpose(v_scale, (2, 0, 1))[..., None].astype(jnp.float32)
         o = paged_decode_attention_quant_kernel(
             q[:, :, None, :], kp, vp, ks, vs, bt, kv_lens,
             scale=scale, interpret=interpret)

@@ -69,7 +69,7 @@ def _fa_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def flash_attention_kernel(q, k, v, kv_len, *, scale, causal=True, window=0,
                            softcap=0.0, block_q=128, block_kv=128,
-                           q_offset=0, interpret=True):
+                           q_offset=0, interpret=False):
     """q: (B,HQ,S,hd) | k/v: (B,HKV,T,hd) | kv_len: (1,) int32 valid bound.
 
     S, T must be multiples of the block sizes and hd 128-aligned on real

@@ -23,7 +23,7 @@ def _pad_to(x, mult, axis):
     "interpret"))
 def flash_attention(q, k, v, kv_len=None, *, scale: float, causal=True,
                     window=0, softcap=0.0, block_q=128, block_kv=128,
-                    interpret=True):
+                    interpret=False):
     """q: (B,HQ,S,hd); k/v: (B,HKV,T,hd); kv_len: scalar int (None -> T)."""
     b, hq, s, hd = q.shape
     t = k.shape[2]

@@ -2,9 +2,9 @@
 
 Each subpackage mirrors the top-level kernel layout — ``kernel.py`` is the
 hand-tiled Pallas TPU kernel, ``ops.py`` the jitted shape-polymorphic
-wrapper, ``ref.py`` the pure-jnp oracle — and every kernel runs in
-interpret mode on CPU so CI exercises the exact code path the rule
-registry substitutes into launch plans (``repro.runtime.rules``).
+wrapper, ``ref.py`` the pure-jnp oracle.  The rule registry
+(``repro.runtime.rules``) substitutes these kernels into launch plans;
+off the TPU the plan executor runs them in interpret mode.
 
 residual_rmsnorm  — residual add + RMSNorm (+ optional plain-norm form):
                     the 9/10-eqn window at every decoder block boundary

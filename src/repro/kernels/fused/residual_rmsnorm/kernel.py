@@ -19,7 +19,10 @@ from jax.experimental import pallas as pl
 def _res_rms_kernel(x_ref, r_ref, w_ref, o_ref, *s_ref, eps, has_residual):
     s = x_ref[...].astype(jnp.float32)
     if has_residual:
+        # the model adds in the stream's dtype, then norms in f32: round
+        # the sum to that dtype first (a no-op for an f32 stream)
         s = s + r_ref[...].astype(jnp.float32)
+        s = s.astype(x_ref.dtype).astype(jnp.float32)
     var = jnp.mean(jnp.square(s), axis=-1, keepdims=True)
     y = s * jax.lax.rsqrt(var + eps) * w_ref[...].astype(jnp.float32)[None]
     o_ref[...] = y.astype(o_ref.dtype)
@@ -36,7 +39,7 @@ def residual_rmsnorm_kernel(
     *,
     eps=1e-5,
     block_n=256,
-    interpret=True,
+    interpret=False,
 ):
     """x: (N, D) -> [normed (N, D)] or [normed, pre-norm sum (N, D)].
 

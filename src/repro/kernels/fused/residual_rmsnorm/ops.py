@@ -12,7 +12,7 @@ from repro.kernels.fused.residual_rmsnorm.kernel import residual_rmsnorm_kernel
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_n", "interpret"))
 def residual_rmsnorm(
-    x, weight, residual=None, *, eps=1e-5, block_n=256, interpret=True
+    x, weight, residual=None, *, eps=1e-5, block_n=256, interpret=False
 ):
     """x: (..., D) -> (normed, pre-norm sum), leading dims flattened.
 

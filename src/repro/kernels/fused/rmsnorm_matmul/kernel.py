@@ -43,7 +43,7 @@ def rmsnorm_matmul_kernel(
     eps=1e-5,
     block_n=256,
     block_f=512,
-    interpret=True,
+    interpret=False,
 ):
     """x: (N, D), weight: (D,), w_proj: (D, F) -> ((N, F), normed (N, D))."""
     n, d = x.shape

@@ -22,7 +22,7 @@ def rmsnorm_matmul(
     eps=1e-5,
     block_n=256,
     block_f=512,
-    interpret=True,
+    interpret=False,
 ):
     """x: (..., D), w_proj: (D, F) -> (proj (..., F), normed (..., D))."""
     shape = x.shape

@@ -25,7 +25,7 @@ def _rms_kernel(x_ref, w_ref, r_ref, o_ref, res_ref, *, eps, has_residual):
 
 
 def rmsnorm_kernel(x, weight, residual=None, *, eps=1e-5, block_n=256,
-                   interpret=True):
+                   interpret=False):
     """x: (N, D) -> (normed (N,D), new_residual (N,D))."""
     n, d = x.shape
     has_res = residual is not None

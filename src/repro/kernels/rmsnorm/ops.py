@@ -11,7 +11,7 @@ from repro.kernels.rmsnorm.kernel import rmsnorm_kernel
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_n", "interpret"))
 def rmsnorm(x, weight, residual=None, *, eps=1e-5, block_n=256,
-            interpret=True):
+            interpret=False):
     """x: (..., D) -> (normed, residual_out) with leading dims flattened."""
     shape = x.shape
     d = shape[-1]

@@ -66,7 +66,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sT_ref,
         sT_ref[0, 0] = s_new.astype(sT_ref.dtype)
 
 
-def wkv6_kernel(r, k, v, logw, u, s0, *, chunk=16, interpret=True):
+def wkv6_kernel(r, k, v, logw, u, s0, *, chunk=16, interpret=False):
     """r,k,v,logw: (B,H,T,hd); u: (H,hd); s0: (B,H,hd,hd).
     T must be a multiple of chunk (ops.py pads).  Returns (o, sT)."""
     b, h, t, hd = r.shape

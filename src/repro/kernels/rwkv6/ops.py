@@ -10,7 +10,7 @@ from repro.kernels.rwkv6.kernel import wkv6_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, logw, u, s0, *, chunk=16, interpret=True):
+def wkv6(r, k, v, logw, u, s0, *, chunk=16, interpret=False):
     """r,k,v,logw: (B,H,T,hd); u: (H,hd); s0: (B,H,hd,hd) -> (o, sT).
 
     Pads T to the chunk multiple with zero k/v and zero log-decay (w=1):

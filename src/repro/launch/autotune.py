@@ -30,6 +30,7 @@ from repro.models import init_params
 from repro.runtime.autotune import (CPU_BOUND_CANDIDATES,
                                     GPU_BOUND_CANDIDATES, autotune)
 from repro.workload import list_scenarios, load_workload
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -56,6 +57,7 @@ def main():
                          "of generating from the scenario")
     ap.add_argument("--out-dir", default="autotune-out")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -51,6 +51,7 @@ from repro.telemetry.characterize import (characterize,
                                           memory_pressure_sweep, spec_sweep,
                                           tp_sweep)
 from repro.workload import list_scenarios, load_workload, save_workload
+from repro.launch.compile_cache import use_compile_cache
 
 
 def write_artifacts(result, out_dir: str) -> dict:
@@ -139,6 +140,7 @@ def main():
                          "table for each batch point (needs a launch-plan "
                          "mode, not --plan jit)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.attribution_report and args.plan == "jit":
         ap.error("--attribution-report needs a launch-plan mode (--plan "
                  "eager/chain/auto/whole_graph/fused): plan=jit has no "

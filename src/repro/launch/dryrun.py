@@ -23,6 +23,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import build_roofline, model_flops
 from repro.launch.steps import StepOptions, build_step
 from repro.models import active_param_count
+from repro.launch.compile_cache import use_compile_cache
 
 
 def embed_param_count(cfg) -> int:
@@ -93,6 +94,7 @@ def main():
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cells = []
     if args.all:

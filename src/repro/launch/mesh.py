@@ -11,8 +11,38 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
-from repro.distributed.compat import require_device_count
+
+def require_device_count(n: int, *, what: str = "mesh") -> None:
+    """Fail fast — and actionably — when a mesh/axis request exceeds the
+    visible device count.
+
+    Without this, ``jax.make_mesh`` surfaces the shortfall as an XLA
+    reshape error deep inside device assignment.  Raised here instead,
+    with the fix inline: on the CPU backend devices are simulated, so the
+    remedy is an env var, not new hardware.
+    """
+    if n < 1:
+        raise ValueError(f"{what} needs a positive device count, got {n}")
+    have = jax.device_count()
+    if n > have:
+        backend = jax.default_backend()
+        hint = (
+            f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
+            f"(before importing jax) to simulate {n} host devices"
+            if backend == "cpu" else
+            f"run on a host with >= {n} {backend} devices")
+        raise ValueError(
+            f"{what} needs {n} devices but jax.device_count() == {have} "
+            f"on backend {backend!r}; {hint}")
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with ``NamedSharding``/``with_sharding_constraint`` and leave the rest
+    to the partitioner, which Explicit axes (the default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +52,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     require_device_count(
         math.prod(shape),
         what=f"production mesh {dict(zip(axes, shape))}")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
@@ -36,7 +66,7 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
     axes = ("pod", "data", "model") if pod else ("data", "model")
     require_device_count(math.prod(shape),
                          what=f"host mesh {dict(zip(axes, shape))}")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_fleet_mesh(replicas: int, tp: int = 1):
@@ -45,9 +75,9 @@ def make_fleet_mesh(replicas: int, tp: int = 1):
     The data axis indexes replicas (each serves whole requests), the
     model axis is each replica's tensor-parallel degree — the same two
     axes training uses, so a deployment can flip between the two without
-    re-slicing its device pool.  Validates the pool holds replicas*tp
-    devices; on an undersized pool (CPU CI) the fleet runs unvalidated
-    with replicas time-multiplexing the local devices instead.
+    re-slicing its device pool.  Raises when the pool holds fewer than
+    replicas*tp devices; ``ReplicaFleet(validate_mesh=True)`` calls it
+    to refuse a fleet that would have to share devices.
     """
     if replicas < 1:
         raise ValueError(f"replicas must be >= 1, got {replicas}")

@@ -29,8 +29,7 @@ import jax
 
 from repro.core.export import save_request_trace
 from repro.core.fusion import json_sanitize
-from repro.inference.engine import (CACHE_MODES, PLAN_STRATEGIES, Request,
-                                    ServeEngine)
+from repro.inference.engine import CACHE_MODES, PLAN_STRATEGIES, Request
 from repro.inference.fleet import ReplicaFleet
 from repro.inference.kv_quant import KV_DTYPES
 from repro.inference.router import POLICIES, RequestRouter
@@ -41,6 +40,7 @@ from repro.telemetry.critical_path import (SLO, analyze, record_goodput,
 from repro.telemetry.metrics import percentile
 from repro.telemetry.tracing import RequestTracer
 from repro.workload import get_scenario, list_scenarios, sample_requests
+from repro.launch.compile_cache import use_compile_cache
 
 
 def build_requests(wl) -> list:
@@ -143,8 +143,8 @@ def main():
                          "--policy prefix-affinity and --share-prefix)")
     ap.add_argument("--validate-mesh", action="store_true",
                     help="require the device pool to hold the "
-                         "(replicas x tp) fleet mesh (default: simulate "
-                         "on whatever devices exist)")
+                         "(replicas x tp) fleet mesh (default: replicas "
+                         "share devices when there are too few)")
     ap.add_argument("--remove-at", type=int, default=None,
                     help="drain replica 0 after this many dispatches")
     ap.add_argument("--add-at", type=int, default=None,
@@ -170,6 +170,7 @@ def main():
                          "(default: the scenario's registered SLO; "
                          "0 disables)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.replicas < 1:
         ap.error(f"--replicas must be >= 1, got {args.replicas}")
     if args.remove_at is not None and args.replicas < 2:
@@ -183,7 +184,12 @@ def main():
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    if args.tp > 1:
+        # straight into the tp layout, never whole on one device
+        from repro.inference.backends.sharded import init_sharded_params
+        params = init_sharded_params(jax.random.PRNGKey(0), cfg, args.tp)
+    else:
+        params = init_params(jax.random.PRNGKey(0), cfg)
 
     engine_kwargs = dict(max_batch=args.max_batch, max_len=args.max_len,
                          plan=args.plan, platform=args.platform,
@@ -199,20 +205,21 @@ def main():
                          time_scale=args.time_scale,
                          shared_prefix=args.shared_prefix_tokens)
 
-    if not args.no_warmup:
-        # pay jit/plan compile on a throwaway engine: replicas share the
-        # process-wide compiled-segment/jit caches, so the measured drain
-        # reports steady-state serving
-        warm = ServeEngine(cfg, params, tp=args.tp, **engine_kwargs)
-        warm.run(build_requests(wl)[:min(2, args.requests)])
-
     # one tracer shared by the router and every replica engine: lifecycle
     # events land on one timeline per request regardless of which replica
-    # served (or re-served) it — the warmup engine above never sees it
+    # served (or re-served) it
     tracer = RequestTracer()
     fleet = ReplicaFleet(cfg, params, replicas=args.replicas, tp=args.tp,
                          validate_mesh=args.validate_mesh, tracer=tracer,
                          **engine_kwargs)
+    if not args.no_warmup:
+        # pay jit/plan compiles on every replica (each holds its own
+        # device and compiled steps), then reset it: the measured drain
+        # reports steady-state serving
+        for rep in fleet.live():
+            rep.engine.run(build_requests(wl)[:min(2, args.requests)])
+            rep.engine.reset()
+        tracer.clear()
 
     def emit(ev):
         print(json.dumps({"stream": {"rid": ev.rid, "replica": ev.replica,

@@ -53,6 +53,7 @@ from repro.models import init_params
 from repro.telemetry.critical_path import (SLO, analyze, record_goodput,
                                            triage)
 from repro.telemetry.tracing import RequestTracer
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -130,6 +131,7 @@ def main():
                          "boundedness verdict in the report (needs a "
                          "launch-plan mode, not --plan jit)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.attribution and args.plan == "jit":
         ap.error("--attribution needs a launch-plan mode (--plan eager/"
                  "chain/auto/whole_graph/fused): plan=jit dispatches one "
@@ -173,7 +175,13 @@ def main():
             validate_draft(cfg, draft_cfg, args.spec_k)
         except ValueError as e:
             ap.error(str(e))
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    if args.tp > 1:
+        # straight into the tp layout: a model that only fits across the
+        # mesh never sits whole on one device
+        from repro.inference.backends.sharded import init_sharded_params
+        params = init_sharded_params(jax.random.PRNGKey(0), cfg, args.tp)
+    else:
+        params = init_params(jax.random.PRNGKey(0), cfg)
     tracer = RequestTracer()
     eng = ServeEngine(cfg, params, max_batch=args.max_batch,
                       tracer=tracer,

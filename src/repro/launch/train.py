@@ -17,6 +17,7 @@ from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig
 from repro.training.loop import TrainConfig, Trainer
 from repro.training.optim import OptConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--fail-at", type=int, default=None,
                     help="simulated failure (restart resumes)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
-
 from repro.configs.base import ModelConfig
 from repro.layers.common import activation, dense_init, split_keys
 
@@ -216,7 +214,8 @@ def _ep_seq_body(params, x, cfg: ModelConfig, dp_axes, tp_axis,
     xt = x.reshape(-1, d)
     t = xt.shape[0]
     m = cfg.moe
-    e_global, e_local = m.n_experts, m.n_experts // axis_size(tp_axis)
+    e_global = m.n_experts
+    e_local = e_global // jax.lax.axis_size(tp_axis)
     cap = capacity(t, cfg)
     gates, eids, aux = route(xt, params["router"], cfg)
     slot, keep = dispatch_slots(eids, e_global, cap)
@@ -228,7 +227,7 @@ def _ep_seq_body(params, x, cfg: ModelConfig, dp_axes, tp_axis,
     # tokens from every peer: (ep*E_local, C, D) with blocks [peer, local_e]
     buf = jax.lax.all_to_all(buf, tp_axis, split_axis=0, concat_axis=0,
                              tiled=True)
-    ep = axis_size(tp_axis)
+    ep = jax.lax.axis_size(tp_axis)
     xb = buf.reshape(ep, e_local, cap, d).transpose(1, 0, 2, 3)
     xb = xb.reshape(e_local, ep * cap, d)
     ys = expert_ffn(params["w_in"], params["w_gate"], params["w_out"], xb, cfg.act)
@@ -254,7 +253,7 @@ def _ep_rep_body(params, x, cfg: ModelConfig, dp_axes, tp_axis,
     params = _gather_experts(params, fsdp_axis)
     bl, s, d = x.shape
     xt = x.reshape(-1, d)
-    ep = axis_size(tp_axis)
+    ep = jax.lax.axis_size(tp_axis)
     e_local = cfg.moe.n_experts // ep
     my = jax.lax.axis_index(tp_axis)
     expert_mask = (jnp.arange(cfg.moe.n_experts) // e_local) == my
@@ -304,7 +303,7 @@ def moe_ep_fwd(params, x, cfg: ModelConfig, dist: MeshContext,
         body = functools.partial(_ep_rep_body, cfg=cfg, dp_axes=dp,
                                  tp_axis=tp, fsdp_axis=fsdp)
         xspec = P(dp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p_, x_: body(p_, x_),
         mesh=dist.mesh,
         in_specs=(wspec, xspec),

@@ -21,6 +21,7 @@ import jax
 import jax.extend.core as jexc
 
 from repro.core.tracing import Trace, _is_drop, _read
+from repro.kernels import pallas_interpret
 from repro.runtime.plan import LaunchPlan, segment_label
 from repro.runtime.rules import get_rule, segment_free_outs
 
@@ -100,7 +101,7 @@ class PlanExecutor:
             if si in rule_map:
                 # rule-tagged segment: ONE fused kernel replaces the
                 # eqn replay (match re-bound here so cached plans stay
-                # self-describing; Pallas interprets off-TPU)
+                # self-describing; Pallas interprets off-TPU only)
                 rule = get_rule(rule_map[si])
                 match = rule.bind(self.trace, seg[0])
                 if match is None:
@@ -108,9 +109,8 @@ class PlanExecutor:
                         f"plan tags segment {si} with rule "
                         f"{rule_map[si]!r} but the trace window no "
                         "longer matches")
-                fused_fn, outs = rule.lower(
-                    match, free,
-                    interpret=jax.default_backend() != "tpu")
+                fused_fn, outs = rule.lower(match, free,
+                                            interpret=pallas_interpret())
                 seg_fns.append((jax.jit(fused_fn), free, outs))
                 continue
 

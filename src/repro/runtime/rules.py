@@ -18,9 +18,11 @@ intermediates escape (consumed outside the window beyond what the fused
 kernel returns) are rejected at bind time, so every fused plan stays an
 exact, numerically-equivalent cover of the trace.
 
-The shipped rules target the fp32 decode hot path (reduced configs and
-CPU CI); bf16 traces interleave ``convert_element_type`` eqns and simply
-do not match — a safe no-op, never a wrong substitution.
+The rules match the fp32 window and its bf16 form, where
+``convert_element_type`` eqns move the stream to f32 for the statistics
+and back.  A bf16 output may differ from the replay by rounding alone,
+so its check allows two of its own ulps at the output's magnitude on
+top of ``tol``.
 """
 from __future__ import annotations
 
@@ -38,6 +40,11 @@ from repro.runtime.plan import LaunchPlan
 # block boundary (fp32: the astype round trips are no-ops and elided)
 _RMSNORM_CORE = ("square", "reduce_sum", "broadcast_in_dim", "div", "add",
                  "rsqrt", "mul", "broadcast_in_dim", "mul")
+# the same core on a low-precision (bf16) stream: x and the weight are
+# converted to f32 for the arithmetic and the product is cast back
+_RMSNORM_CAST = (("convert_element_type",) + _RMSNORM_CORE[:7]
+                 + ("convert_element_type",) + _RMSNORM_CORE[7:]
+                 + ("convert_element_type",))
 
 DEFAULT_TOL = 1e-4
 
@@ -115,6 +122,7 @@ class RuleMatch:
     provides: dict                     # outvar -> fused-result index
     eps: float
     max_abs_err: float = float("nan")  # numeric check result (nan = unchecked)
+    round_bound: float = 0.0           # rounding allowance of bf16 outputs
 
     @property
     def indices(self) -> tuple:
@@ -138,15 +146,18 @@ class RMSNormRule:
 
     ``residual`` prepends the block-boundary ``add``; ``matmul`` appends
     the projection ``dot_general``.  All three lower to the fused Pallas
-    kernels in ``repro.kernels.fused`` (interpret mode off-TPU).
+    kernels in ``repro.kernels.fused``; the caller says whether they
+    interpret (``repro.kernels.pallas_interpret``).
     """
     name: str
     residual: bool = False
     matmul: bool = False
 
-    @property
-    def pattern(self) -> tuple:
-        pat = _RMSNORM_CORE
+    def pattern(self, cast: bool = False) -> tuple:
+        """Eqn names of the window.  ``cast`` is the low-precision form: a
+        bf16 stream is converted to f32 for the statistics, the weight is
+        converted before its broadcast, and the product is cast back."""
+        pat = _RMSNORM_CAST if cast else _RMSNORM_CORE
         if self.residual:
             pat = ("add",) + pat
         if self.matmul:
@@ -156,23 +167,41 @@ class RMSNormRule:
     # ------------------------------------------------------------ bind
     def bind(self, trace: Trace, start: int) -> Optional[RuleMatch]:
         flat = trace.flat_eqns
-        stop = start + len(self.pattern)
-        if stop > len(flat):
-            return None
-        eqns = [flat[i] for i in range(start, stop)]
-        if tuple(e.primitive.name for e, _ in eqns) != self.pattern:
-            return None
+        for cast in (False, True):
+            pat = self.pattern(cast)
+            stop = start + len(pat)
+            if stop <= len(flat) and tuple(
+                    flat[i][0].primitive.name
+                    for i in range(start, stop)) == pat:
+                return self._bind(trace, start, stop, cast)
+        return None
 
-        off = 1 if self.residual else 0
-        (sq, rs, bc1, dv, ad, rq, m1, bc2, m2) = eqns[off:off + 9]
+    def _bind(self, trace: Trace, start: int, stop: int,
+              cast: bool) -> Optional[RuleMatch]:
+        eqns = [trace.flat_eqns[i] for i in range(start, stop)]
+        body = eqns[(1 if self.residual else 0):
+                    len(eqns) - (1 if self.matmul else 0)]
+        if cast:
+            (cx, sq, rs, bc1, dv, ad, rq, m1, cw, bc2, m2, co) = body
+        else:
+            (sq, rs, bc1, dv, ad, rq, m1, bc2, m2) = body
 
         def out(e):
             return e[0].outvars[0]
 
-        x_ref = sq[1][0]
+        x_ref = (cx if cast else sq)[1][0]
         x_b = _base(x_ref)
         if isinstance(x_b, jexc.Literal):
             return None
+        # the f32 stream the statistics read (x itself without casts)
+        xf = out(cx) if cast else x_b
+        if cast:
+            x_dtype = cx[0].invars[0].aval.dtype
+            if (cx[0].params.get("new_dtype") != np.float32
+                    or cw[0].params.get("new_dtype") != np.float32
+                    or co[0].params.get("new_dtype") != x_dtype
+                    or _base(sq[1][0]) is not xf):
+                return None
         x_aval = sq[0].invars[0].aval
         if len(x_aval.shape) < 1:
             return None
@@ -201,20 +230,25 @@ class RMSNormRule:
         if _base(rq[1][0]) is not out(ad):
             return None
         m1_bases = [_base(v) for v in _var_operands(m1[1])]
-        if out(rq) not in m1_bases or x_b not in m1_bases:
+        if out(rq) not in m1_bases or xf not in m1_bases:
             return None
-        w_ref = bc2[1][0]
-        w_aval = bc2[0].invars[0].aval
-        if tuple(w_aval.shape) != (d,):
+        w_eqn = cw if cast else bc2
+        w_ref = w_eqn[1][0]
+        if tuple(w_eqn[0].invars[0].aval.shape) != (d,):
+            return None
+        if cast and _base(bc2[1][0]) is not out(cw):
             return None
         if bc2[0].params.get("broadcast_dimensions") != (axis,):
             return None
         m2_bases = [_base(v) for v in _var_operands(m2[1])]
         if out(m1) not in m2_bases or out(bc2) not in m2_bases:
             return None
+        if cast and _base(co[1][0]) is not out(m2):
+            return None
+        normed = out(co) if cast else out(m2)
 
         inputs = {"x": x_ref, "weight": w_ref}
-        provides = {out(m2): 0}
+        provides = {normed: 0}
 
         if self.residual:
             add0 = eqns[0]
@@ -229,14 +263,14 @@ class RMSNormRule:
                     return None
             inputs = {"x": a_ref, "residual": b_ref, "weight": w_ref}
             # fused result order: (normed, pre-norm sum)
-            provides = {out(m2): 0, out(add0): 1}
+            provides = {normed: 0, out(add0): 1}
 
         if self.matmul:
             dot = eqns[-1]
             dims = dot[0].params.get("dimension_numbers")
             if dims != (((axis,), (0,)), ((), ())):
                 return None
-            if _base(dot[1][0]) is not out(m2):
+            if _base(dot[1][0]) is not normed:
                 return None
             p_ref = dot[1][1]
             p_aval = dot[0].invars[1].aval
@@ -244,7 +278,7 @@ class RMSNormRule:
                 return None
             inputs["w_proj"] = p_ref
             # fused result order: (projection, normed)
-            provides = {out(dot): 0, out(m2): 1}
+            provides = {out(dot): 0, normed: 1}
 
         # every escaping intermediate must be one the kernel returns
         if not live_outs(trace, start, stop) <= set(provides):
@@ -253,7 +287,7 @@ class RMSNormRule:
                          eps=float(lit_eps.val))
 
     # ------------------------------------------------------------ lower
-    def lower(self, match: RuleMatch, free: Sequence, interpret: bool = True):
+    def lower(self, match: RuleMatch, free: Sequence, *, interpret: bool):
         """Fused callable over the segment's free-var values, plus the
         ordered outvars it defines (``PlanExecutor`` seg_fn contract)."""
         from repro.kernels.fused import residual_rmsnorm, rmsnorm_matmul
@@ -318,7 +352,8 @@ def verify_match(trace: Trace, match: RuleMatch) -> float:
     eqns, free, _ = segment_free_outs(trace.flat_eqns, seg)
     key = _window_signature(trace, match, free)
     if key in _VERIFY_CACHE:
-        return _VERIFY_CACHE[key]
+        match.max_abs_err, match.round_bound = _VERIFY_CACHE[key]
+        return match.max_abs_err
 
     rng = np.random.default_rng(0)
     vals = []
@@ -340,15 +375,19 @@ def verify_match(trace: Trace, match: RuleMatch) -> float:
             if not _is_drop(ov):
                 env[ov] = o
 
+    from repro.kernels import pallas_interpret
     rule = get_rule(match.rule_name)
-    fused_fn, outs = rule.lower(match, free)
+    fused_fn, outs = rule.lower(match, free, interpret=pallas_interpret())
     fused = fused_fn(vals)
-    err = 0.0
+    err = bound = 0.0
     for ov, o in zip(outs, fused):
         ref = np.asarray(env[ov], np.float64)
         err = max(err, float(np.max(np.abs(ref - np.asarray(o, np.float64)))))
-    _VERIFY_CACHE[key] = err
-    match.max_abs_err = err
+        if jax.numpy.dtype(o.dtype).itemsize < 4:
+            ulp = float(jax.numpy.finfo(o.dtype).eps)
+            bound = max(bound, 2 * ulp * float(np.max(np.abs(ref))))
+    _VERIFY_CACHE[key] = (err, bound)
+    match.max_abs_err, match.round_bound = err, bound
     return err
 
 
@@ -364,15 +403,12 @@ def find_matches(trace: Trace, rules: Sequence[str] = DEFAULT_RULES, *,
         hit = None
         for rn in rules:
             rule = get_rule(rn)
-            if names[pos] != rule.pattern[0]:
-                continue
             m = rule.bind(trace, pos)
             if m is None:
                 continue
             if verify:
                 err = verify_match(trace, m)
-                m.max_abs_err = err
-                if not (err <= tol):
+                if not (err <= tol + m.round_bound):
                     continue
             hit = m
             break

@@ -314,3 +314,97 @@ def test_sharded_serve_cli_reports_tp_counters():
     print("CLI_OK")
     """
     assert "CLI_OK" in _run_sub(code)
+
+
+# ------------------------------------------------------------ placement
+def test_local_backend_reports_its_device(tiny):
+    cfg, params = tiny
+    dev = jax.devices()[-1]
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=32, device=dev)
+    assert eng.backend.info.devices == (dev.id,)
+    for leaf in jax.tree.leaves((eng.backend.params, eng.cache)):
+        assert leaf.devices() == {dev}
+    with pytest.raises(ValueError, match="tp=1"):
+        ServeEngine(cfg, params, max_batch=2, max_len=32, tp=2, device=dev)
+
+
+def test_fleet_pins_replicas_to_devices():
+    code = """
+    import jax, numpy as np
+    from repro.configs import get_config, reduced
+    from repro.inference.engine import Request, ServeEngine
+    from repro.inference.fleet import ReplicaFleet
+    from repro.inference.router import RequestRouter
+    from repro.models import init_params
+    cfg = reduced(get_config("smollm-360m"), n_layers=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    def reqs():
+        rng = np.random.default_rng(0)
+        return [Request(i, prompt=list(rng.integers(0, cfg.vocab_size, 9)),
+                        max_new_tokens=4) for i in range(8)]
+    kw = dict(max_batch=2, max_len=32, cache="paged", block_size=4)
+    fleet = ReplicaFleet(cfg, params, replicas=4, **kw)
+    devs = [rep.engine.backend.info.devices for rep in fleet.live()]
+    assert devs == [(0,), (1,), (2,), (3,)], devs
+    for rep in fleet.live():
+        (d,) = rep.engine.backend.info.devices
+        for leaf in jax.tree.leaves((rep.engine.backend.params,
+                                     rep.engine.cache)):
+            assert {x.id for x in leaf.devices()} == {d}
+    got = RequestRouter(fleet, policy="round-robin").route(reqs())
+    ref = {r.rid: list(r.generated)
+           for r in ServeEngine(cfg, params, **kw).run(reqs())}
+    assert got.tokens_by_rid == ref
+    # a fifth replica goes to the least-loaded device, lowest index first
+    assert fleet.add_replica().engine.backend.info.devices == (0,)
+    print("FLEET_DEVICES_OK")
+    """
+    assert "FLEET_DEVICES_OK" in _run_sub(code)
+
+
+def test_init_sharded_params_builds_into_shardings():
+    code = """
+    import jax, numpy as np
+    from repro.configs import get_config, reduced
+    from repro.inference.backends.sharded import init_sharded_params
+    from repro.inference.engine import ServeEngine
+    from repro.models import init_params
+    cfg = reduced(get_config("codeqwen1.5-7b"), n_layers=2)
+    key = jax.random.PRNGKey(0)
+    sharded = init_sharded_params(key, cfg, 4)
+    whole = jax.jit(lambda: init_params(key, cfg))()
+    for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(whole)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert len(a.sharding.device_set) == 4
+    wq = sharded["blocks"]["slot0"]["mixer"]["wq"]
+    assert wq.sharding.spec[-1] == "model"
+    assert wq.addressable_shards[0].data.shape[-1] == wq.shape[-1] // 4
+    eng = ServeEngine(cfg, sharded, max_batch=2, max_len=32, tp=4)
+    for leaf in jax.tree.leaves(eng.cache):
+        assert len(leaf.sharding.device_set) == 4
+    print("SHARDED_INIT_OK")
+    """
+    assert "SHARDED_INIT_OK" in _run_sub(code)
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_dir(env_dir, tmp_path):
+    code = f"""
+    import os
+    if {env_dir}:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = {str(tmp_path)!r}
+    import jax, jax.numpy as jnp
+    from repro.launch.compile_cache import DEFAULT_CACHE_DIR, use_compile_cache
+    used = use_compile_cache()
+    assert DEFAULT_CACHE_DIR.parent.joinpath("src", "repro").is_dir()
+    assert DEFAULT_CACHE_DIR.name == ".jax_cache"
+    if {env_dir}:
+        assert used == {str(tmp_path)!r}, used
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.jit(lambda x: x * 2 + 1)(jnp.arange(4)).block_until_ready()
+        assert os.listdir(used)
+    else:
+        assert used == str(DEFAULT_CACHE_DIR), used
+    print("CACHE_OK")
+    """
+    assert "CACHE_OK" in _run_sub(code, devices=1)

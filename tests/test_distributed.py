@@ -4,6 +4,7 @@ host-platform device count (the main test process keeps 1 device)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import pytest
@@ -19,11 +20,13 @@ def _run_sub(code: str, devices: int = 8) -> str:
     if jax.default_backend() != "cpu" and jax.device_count() < devices:
         pytest.skip(f"needs {devices} devices, have {jax.device_count()} "
                     f"on backend {jax.default_backend()!r}")
+    repo = Path(__file__).resolve().parents[1]
     env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
-           "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"}
+           "PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": "/usr/bin:/bin"}
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
-                         cwd="/root/repo", timeout=560)
+                         cwd=str(repo), timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 

@@ -32,7 +32,7 @@ def test_residual_rmsnorm_matches_ref(shape):
     x = jax.random.normal(ks[0], shape)
     r = jax.random.normal(ks[1], shape)
     w = jax.random.normal(ks[2], (d,))
-    y, s = residual_rmsnorm(x, w, r)
+    y, s = residual_rmsnorm(x, w, r, interpret=True)
     y_ref, s_ref = residual_rmsnorm_ref(x.reshape(-1, d), w,
                                         r.reshape(-1, d))
     np.testing.assert_allclose(np.asarray(y).reshape(-1, d),
@@ -46,7 +46,7 @@ def test_plain_rmsnorm_matches_layer_oracle():
     — the exact op the decode trace windows come from."""
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 1, 48))
     w = jax.random.normal(jax.random.PRNGKey(1), (48,))
-    y, s = residual_rmsnorm(x, w)
+    y, s = residual_rmsnorm(x, w, interpret=True)
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(rmsnorm_layer(x, w)), atol=1e-6)
     np.testing.assert_allclose(np.asarray(s), np.asarray(x), atol=0)
@@ -58,7 +58,7 @@ def test_rmsnorm_matmul_matches_ref(n, d, f):
     x = jax.random.normal(ks[0], (n, d))
     w = jax.random.normal(ks[1], (d,))
     p = jax.random.normal(ks[2], (d, f))
-    y, normed = rmsnorm_matmul(x, w, p)
+    y, normed = rmsnorm_matmul(x, w, p, interpret=True)
     y_ref, normed_ref = rmsnorm_matmul_ref(x, w, p)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-5)
     np.testing.assert_allclose(np.asarray(normed), np.asarray(normed_ref),
@@ -66,8 +66,8 @@ def test_rmsnorm_matmul_matches_ref(n, d, f):
 
 
 # ------------------------------------------------------------ rule registry
-def _decode_setup(n_layers=2):
-    cfg = reduced(get_config("smollm-360m"), n_layers=n_layers)
+def _decode_setup(n_layers=2, **overrides):
+    cfg = reduced(get_config("smollm-360m"), n_layers=n_layers, **overrides)
     params = init_params(jax.random.PRNGKey(0), cfg)
     cache = make_cache(cfg, 1, 64, src_len=1, dtype=cfg.cdtype)
     toks = jnp.zeros((1, 1), jnp.int32)
@@ -98,6 +98,28 @@ def test_rules_match_real_decode_trace():
     assert starts == sorted(starts)
     for a, b in zip(matches, matches[1:]):
         assert a.stop <= b.start
+
+
+def test_rules_match_bf16_decode_trace():
+    """The published dtype: a bf16 stream converts to f32 around the norm,
+    and every rule still binds and passes its check, within the bf16
+    rounding allowance."""
+    _, _, trace, args = _decode_setup(param_dtype="bfloat16",
+                                      compute_dtype="bfloat16")
+    matches = find_matches(trace)
+    assert {m.rule_name for m in matches} == {
+        "rmsnorm_matmul", "residual_rmsnorm", "rmsnorm"}
+    for m in matches:
+        assert 0.0 < m.round_bound
+        assert m.max_abs_err <= 1e-4 + m.round_bound
+    n = len(trace.kernels)
+    eager, _ = PlanExecutor(trace, LaunchPlan.eager(n)).run(*args)
+    out, _ = PlanExecutor(trace, fused_plan(trace, matches=matches)).run(*args)
+    for a, b in zip(eager, out):
+        a = np.asarray(a, np.float32)
+        tol = 2 * float(jnp.finfo(jnp.bfloat16).eps) * max(
+            float(np.max(np.abs(a))), 1.0)
+        np.testing.assert_allclose(a, np.asarray(b, np.float32), atol=tol)
 
 
 def test_fused_plan_is_exact_cover_with_rule_tags():

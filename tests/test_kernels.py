@@ -29,7 +29,7 @@ def test_flash_attention_shapes(shape, dtype):
     q = jax.random.normal(ks[0], (B, HQ, S, hd)).astype(dtype)
     k = jax.random.normal(ks[1], (B, HKV, T, hd)).astype(dtype)
     v = jax.random.normal(ks[2], (B, HKV, T, hd)).astype(dtype)
-    o = flash_attention(q, k, v, scale=0.2, causal=True,
+    o = flash_attention(q, k, v, interpret=True, scale=0.2, causal=True,
                         block_q=32, block_kv=32)
     r = attention_ref(q, k, v, scale=0.2, causal=True)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -44,7 +44,7 @@ def test_flash_attention_window_softcap(window, cap):
     q = jax.random.normal(ks[0], (B, HQ, S, hd))
     k = jax.random.normal(ks[1], (B, HKV, T, hd))
     v = jax.random.normal(ks[2], (B, HKV, T, hd))
-    o = flash_attention(q, k, v, scale=0.2, causal=True, window=window,
+    o = flash_attention(q, k, v, interpret=True, scale=0.2, causal=True, window=window,
                         softcap=cap, block_q=16, block_kv=16)
     r = attention_ref(q, k, v, scale=0.2, causal=True, window=window,
                       softcap=cap)
@@ -59,7 +59,7 @@ def test_flash_attention_property(s, hkv, g, hd):
     q = jax.random.normal(ks[0], (1, hkv * g, s, hd))
     k = jax.random.normal(ks[1], (1, hkv, s, hd))
     v = jax.random.normal(ks[2], (1, hkv, s, hd))
-    o = flash_attention(q, k, v, scale=hd ** -0.5, causal=True,
+    o = flash_attention(q, k, v, interpret=True, scale=hd ** -0.5, causal=True,
                         block_q=16, block_kv=16)
     r = attention_ref(q, k, v, scale=hd ** -0.5, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
@@ -77,7 +77,8 @@ def test_decode_attention_shapes(shape, dtype):
     k = jax.random.normal(ks[1], (B, HKV, T, hd)).astype(dtype)
     v = jax.random.normal(ks[2], (B, HKV, T, hd)).astype(dtype)
     for kvlen in (T, T // 2, 5):
-        o = decode_attention(q, k, v, kvlen, scale=0.2, block_kv=64)
+        o = decode_attention(q, k, v, kvlen, scale=0.2, block_kv=64,
+                             interpret=True)
         r = decode_attention_ref(q, k, v, kvlen, scale=0.2)
         tol = 2e-5 if dtype == jnp.float32 else 2e-2
         np.testing.assert_allclose(np.asarray(o, np.float32),
@@ -92,7 +93,7 @@ def test_rmsnorm(n, d, with_res):
     x = jax.random.normal(KEY, (n, d))
     w = jax.random.normal(jax.random.PRNGKey(1), (d,)) + 1.0
     res = jax.random.normal(jax.random.PRNGKey(2), (n, d)) if with_res else None
-    y, r2 = rmsnorm(x, w, res, block_n=32)
+    y, r2 = rmsnorm(x, w, res, block_n=32, interpret=True)
     yr, rr = rmsnorm_ref(x, w, res)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5)
     np.testing.assert_allclose(np.asarray(r2), np.asarray(rr), atol=1e-6)
@@ -103,7 +104,7 @@ def test_rmsnorm(n, d, with_res):
 def test_rmsnorm_property(n, d):
     x = jax.random.normal(jax.random.PRNGKey(n), (n, d))
     w = jnp.ones((d,))
-    y, _ = rmsnorm(x, w, block_n=16)
+    y, _ = rmsnorm(x, w, block_n=16, interpret=True)
     yr, _ = rmsnorm_ref(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                atol=2e-5, rtol=2e-5)
@@ -121,7 +122,7 @@ def test_wkv6(shape):
     logw = -jnp.exp(jax.random.normal(ks[3], (B, H, T, hd)) * 0.5 - 2)
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
     s0 = jax.random.normal(ks[5], (B, H, hd, hd)) * 0.1
-    o, sT = wkv6(r, k, v, logw, u, s0, chunk=16)
+    o, sT = wkv6(r, k, v, logw, u, s0, chunk=16, interpret=True)
     orf, srf = wkv6_ref(r, k, v, logw, u, s0)
     np.testing.assert_allclose(np.asarray(o), np.asarray(orf),
                                atol=5e-4, rtol=1e-3)
@@ -141,7 +142,7 @@ def test_wkv6_extreme_decay():
     logw = jnp.broadcast_to(logw, (B, H, T, hd))
     u = jnp.zeros((H, hd))
     s0 = jnp.zeros((B, H, hd, hd))
-    o, sT = wkv6(r, k, v, logw, u, s0, chunk=8)
+    o, sT = wkv6(r, k, v, logw, u, s0, chunk=8, interpret=True)
     orf, srf = wkv6_ref(r, k, v, logw, u, s0)
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(np.asarray(o), np.asarray(orf),
@@ -163,7 +164,8 @@ def test_wkv6_matches_model_chunked():
     # kernel uses (B,H,T,hd) layout
     def tr(x):
         return x.transpose(0, 2, 1, 3)
-    o_kern, s_kern = wkv6(tr(r), tr(k), tr(v), tr(logw), u, s0, chunk=16)
+    o_kern, s_kern = wkv6(tr(r), tr(k), tr(v), tr(logw), u, s0, chunk=16,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(tr(o_kern)), np.asarray(o_model),
                                atol=5e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(s_kern), np.asarray(s_model),

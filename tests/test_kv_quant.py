@@ -151,7 +151,7 @@ def test_quant_paged_kernel_matches_quant_ref(shape, bs):
     q, _, _, qk, sk, qv, sv, tables, lens = _quant_pool(b, hq, hkv, t, hd,
                                                         bs)
     o = paged_decode_attention(q, qk, qv, tables, lens, scale=0.2,
-                               k_scale=sk, v_scale=sv)
+                               k_scale=sk, v_scale=sv, interpret=True)
     r = paged_decode_attention_quant_ref(q, qk, qv, sk, sv, tables, lens,
                                          scale=0.2)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
@@ -163,7 +163,7 @@ def test_quant_paged_kernel_tolerance_vs_fp_oracle():
     q, kp, vp, qk, sk, qv, sv, tables, lens = _quant_pool(b, hq, hkv, t,
                                                           hd, bs)
     o = paged_decode_attention(q, qk, qv, tables, lens, scale=0.2,
-                               k_scale=sk, v_scale=sv)
+                               k_scale=sk, v_scale=sv, interpret=True)
     fp = paged_decode_attention_ref(q, kp, vp, tables, lens, scale=0.2)
     # stated decode tolerance of the int8 path vs the exact fp pool: the
     # softmax mix of <=scale/2 per-element dequant error stays well under
@@ -171,7 +171,8 @@ def test_quant_paged_kernel_tolerance_vs_fp_oracle():
     err = np.abs(np.asarray(o) - np.asarray(fp)).max()
     assert err < 5e-2, err
     # and the unquantized call on the SAME wrapper is unaffected
-    o_fp = paged_decode_attention(q, kp, vp, tables, lens, scale=0.2)
+    o_fp = paged_decode_attention(q, kp, vp, tables, lens, scale=0.2,
+                                  interpret=True)
     np.testing.assert_allclose(np.asarray(o_fp), np.asarray(fp),
                                atol=2e-5, rtol=2e-5)
 

@@ -115,7 +115,8 @@ def test_paged_kernel_vs_refs(shape, bs):
     lens = np.array([t - 3 * i for i in range(b)], np.int32)
     kp, vp, tables = _scatter_pages(k, v, lens, bs, n_pages)
     tj, lj = jnp.asarray(tables), jnp.asarray(lens)
-    o = paged_decode_attention(q, kp, vp, tj, lj, scale=0.2)
+    o = paged_decode_attention(q, kp, vp, tj, lj, scale=0.2,
+                               interpret=True)
     r = paged_decode_attention_ref(q, kp, vp, tj, lj, scale=0.2)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                atol=2e-5, rtol=2e-5)
@@ -137,11 +138,13 @@ def test_paged_kernel_ignores_sentinel_table_entries():
     lens = np.array([9], np.int32)                 # 2 of 4 pages valid
     kp, vp, tables = _scatter_pages(k, v, lens, bs, n_pages)
     o1 = paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                jnp.asarray(lens), scale=0.2)
+                                jnp.asarray(lens), scale=0.2,
+                                interpret=True)
     garbage = tables.copy()
     garbage[0, 2:] = [0, n_pages + 1000]           # valid-range AND huge ids
     o2 = paged_decode_attention(q, kp, vp, jnp.asarray(garbage),
-                                jnp.asarray(lens), scale=0.2)
+                                jnp.asarray(lens), scale=0.2,
+                                interpret=True)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 

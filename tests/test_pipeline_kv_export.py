@@ -3,6 +3,7 @@ import json
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +22,13 @@ def _run_sub(code: str, devices: int = 4) -> str:
     if jax.default_backend() != "cpu" and jax.device_count() < devices:
         pytest.skip(f"needs {devices} devices, have {jax.device_count()} "
                     f"on backend {jax.default_backend()!r}")
+    repo = Path(__file__).resolve().parents[1]
     env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
-           "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin"}
+           "PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": "/usr/bin:/bin"}
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
-                         cwd="/root/repo", timeout=560)
+                         cwd=str(repo), timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -36,7 +39,8 @@ def test_pipeline_matches_sequential_multidevice():
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_forward, reference_forward
     P, n_micro, mb, d = 4, 6, 2, 8
-    mesh = jax.make_mesh((P,), ("pipe",))
+    mesh = jax.make_mesh((P,), ("pipe",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     params = {"w": jax.random.normal(key, (P, d, d)) * 0.3,
               "b": jax.random.normal(key, (P, d)) * 0.1}
@@ -52,11 +56,29 @@ def test_pipeline_matches_sequential_multidevice():
     assert "PP_OK" in _run_sub(code)
 
 
+def test_pipeline_explicit_mesh_multidevice():
+    """A ``pipe`` axis of the default (Explicit) type: the last stage's
+    gather names its output sharding instead of leaving it ambiguous."""
+    code = """
+    import jax, jax.numpy as jnp
+    from repro.distributed.pipeline import pipeline_forward, reference_forward
+    mesh = jax.make_mesh((4,), ("pipe",))
+    params = {"w": jax.random.normal(jax.random.PRNGKey(0), (4, 8, 8)) * 0.3}
+    x = jax.random.normal(jax.random.PRNGKey(1), (5, 2, 8))
+    stage_fn = lambda p, x_: jnp.tanh(x_ @ p["w"])
+    y = pipeline_forward(stage_fn, params, x, mesh)
+    assert float(jnp.max(jnp.abs(y - reference_forward(stage_fn, params, x)))) < 1e-5
+    print("PPX_OK")
+    """
+    assert "PPX_OK" in _run_sub(code)
+
+
 def test_pipeline_single_stage_degenerates():
     code = """
     import jax, jax.numpy as jnp
     from repro.distributed.pipeline import pipeline_forward, reference_forward
-    mesh = jax.make_mesh((1,), ("pipe",))
+    mesh = jax.make_mesh((1,), ("pipe",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     params = {"w": jnp.eye(4)[None] * 2.0}
     x = jnp.ones((3, 2, 4))
     y = pipeline_forward(lambda p, x_: x_ @ p["w"], params, x, mesh)

@@ -1,7 +1,8 @@
-"""Chrome-trace (chrome://tracing / Perfetto) export of simulated SKIP
-timelines — host lane (launch calls) + device lane (kernel execution),
-so the CPU-bound launch trains and GPU-bound queue pileups of the paper's
-Fig. 4 are visually inspectable.
+"""Chrome-trace (chrome://tracing / Perfetto) export: measured host spans
+merged with the modeled device lane of a launch plan (launch calls ->
+kernel execution, so the CPU-bound launch trains and GPU-bound queue
+pileups of the paper's Fig. 4 are visually inspectable), and per-request
+critical-path tracks.
 """
 from __future__ import annotations
 
@@ -24,49 +25,6 @@ def _flow_pair(name: str, flow_id: int, host_ts_us: float,
          "ts": device_ts_us, "id": flow_id, "cat": "dispatch_flow",
          "bp": "e"},
     ]
-
-
-def to_chrome_trace(events: Sequence[KernelEvent], platform: str) -> dict:
-    out = []
-    for i, e in enumerate(events):
-        args = {"t_l_us": e.t_l * 1e6, "queue_us": e.t_queue * 1e6}
-        if getattr(e, "operator", ""):
-            args["operator"] = e.operator
-        out.append({
-            "name": e.name, "ph": "X", "pid": 0, "tid": 0,
-            "ts": e.launch_begin * 1e6,
-            "dur": max(e.t_launch * 1e6, 0.01),
-            "cat": "host_launch",
-        })
-        out.append({
-            "name": e.name, "ph": "X", "pid": 0, "tid": 1,
-            "ts": e.kernel_start * 1e6,
-            "dur": max(e.duration * 1e6, 0.01),
-            "cat": "kernel",
-            "args": args,
-        })
-        # arrow from this launch call to the kernel it enqueued: the
-        # start event must land INSIDE the host slice, so nudge past
-        # launch_begin by a fraction of the (clamped) slice duration
-        out.extend(_flow_pair(e.name, i,
-                              e.launch_begin * 1e6
-                              + 0.5 * max(e.t_launch * 1e6, 0.01),
-                              e.kernel_start * 1e6, 0, 1))
-    return {
-        "traceEvents": out,
-        "displayTimeUnit": "ms",
-        "metadata": {"platform": platform},
-        "otherData": {
-            "thread_names": {"0": "CPU (launch calls)",
-                             "1": f"{platform} stream 0"},
-        },
-    }
-
-
-def save_chrome_trace(events, platform: str, path: str) -> str:
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(events, platform), f)
-    return path
 
 
 # --------------------------------------------------------------- measured

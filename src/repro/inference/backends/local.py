@@ -22,6 +22,7 @@ from repro.inference.backends.base import (AccountingMixin, BackendInfo,
                                            CallAccount)
 from repro.inference.backends.bodies import make_step_bodies
 from repro.models import make_cache
+from repro.telemetry.spans import profiler_span
 
 
 class _PlannedFn:
@@ -177,8 +178,9 @@ class LocalBackend(AccountingMixin):
         (last-position logits, updated cache)."""
         if self.plan == "jit":
             t0 = time.perf_counter()
-            logits, cache = self._prefill(self.params, cache, tokens,
-                                          slot, plen)
+            with profiler_span("launch.prefill_body"):
+                logits, cache = self._prefill(self.params, cache, tokens,
+                                              slot, plen)
             self._jit_account(t0)
             return logits, cache
         bucket = tokens.shape[1]
@@ -188,8 +190,9 @@ class LocalBackend(AccountingMixin):
                                    unroll=True)
             pf = _PlannedFn(fn, self.plan, self.platform)
             self._planned_prefill[(bucket, plen)] = pf
-        logits, cache = pf(self.params, cache, tokens,
-                           jnp.asarray(slot, jnp.int32))
+        with profiler_span("launch.prefill_body"):
+            logits, cache = pf(self.params, cache, tokens,
+                               jnp.asarray(slot, jnp.int32))
         self._planned_account(pf)
         return logits, cache
 
@@ -197,15 +200,18 @@ class LocalBackend(AccountingMixin):
         """One batched decode step over the contiguous cache."""
         if self.plan == "jit":
             t0 = time.perf_counter()
-            logits, cache = self._decode(self.params, cache, tokens, lengths)
+            with profiler_span("launch.decode_body"):
+                logits, cache = self._decode(self.params, cache, tokens,
+                                             lengths)
             self._jit_account(t0)
             return logits, cache
         if self._planned_decode is None:
             self._planned_decode = _PlannedFn(
                 functools.partial(self._decode_body, unroll=True),
                 self.plan, self.platform)
-        logits, cache = self._planned_decode(self.params, cache, tokens,
-                                             lengths)
+        with profiler_span("launch.decode_body"):
+            logits, cache = self._planned_decode(self.params, cache, tokens,
+                                                 lengths)
         self._planned_account(self._planned_decode)
         return logits, cache
 
@@ -213,8 +219,9 @@ class LocalBackend(AccountingMixin):
         """Write one prompt chunk into paged KV through a block table."""
         if self.plan == "jit":
             t0 = time.perf_counter()
-            logits, cache = self._prefill_paged(self.params, cache, tokens,
-                                                bt_row, t0_index)
+            with profiler_span("launch.prefill_chunk"):
+                logits, cache = self._prefill_paged(self.params, cache,
+                                                    tokens, bt_row, t0_index)
             self._jit_account(t0)
             return logits, cache
         chunk_len = tokens.shape[1]
@@ -223,7 +230,8 @@ class LocalBackend(AccountingMixin):
             fn = functools.partial(self._paged_prefill_body, unroll=True)
             pf = _PlannedFn(fn, self.plan, self.platform)
             self._planned_prefill[("paged", chunk_len)] = pf
-        logits, cache = pf(self.params, cache, tokens, bt_row, t0_index)
+        with profiler_span("launch.prefill_chunk"):
+            logits, cache = pf(self.params, cache, tokens, bt_row, t0_index)
         self._planned_account(pf)
         return logits, cache
 
@@ -231,16 +239,19 @@ class LocalBackend(AccountingMixin):
         """One batched decode step gathering KV through block tables."""
         if self.plan == "jit":
             t0 = time.perf_counter()
-            logits, cache = self._decode_paged(self.params, cache, tokens,
-                                               lengths, block_tables)
+            with profiler_span("launch.decode"):
+                logits, cache = self._decode_paged(self.params, cache,
+                                                   tokens, lengths,
+                                                   block_tables)
             self._jit_account(t0)
             return logits, cache
         if self._planned_decode is None:
             self._planned_decode = _PlannedFn(
                 functools.partial(self._paged_decode_body, unroll=True),
                 self.plan, self.platform)
-        logits, cache = self._planned_decode(self.params, cache, tokens,
-                                             lengths, block_tables)
+        with profiler_span("launch.decode"):
+            logits, cache = self._planned_decode(self.params, cache, tokens,
+                                                 lengths, block_tables)
         self._planned_account(self._planned_decode)
         return logits, cache
 
@@ -251,15 +262,17 @@ class LocalBackend(AccountingMixin):
         # draft/verify launch trade is priced by Planner(draft_launches=)
         # / telemetry.characterize.spec_sweep instead
         t0 = time.perf_counter()
-        logits, cache = self._verify(self.params, cache, tokens, lengths)
+        with profiler_span("launch.verify_body"):
+            logits, cache = self._verify(self.params, cache, tokens, lengths)
         self._jit_account(t0)
         return logits, cache
 
     def paged_verify(self, cache, tokens, lengths, block_tables):
         """Paged-cache variant of ``verify``."""
         t0 = time.perf_counter()
-        logits, cache = self._verify_paged(self.params, cache, tokens,
-                                           lengths, block_tables)
+        with profiler_span("launch.paged_verify_body"):
+            logits, cache = self._verify_paged(self.params, cache, tokens,
+                                               lengths, block_tables)
         self._jit_account(t0)
         return logits, cache
 

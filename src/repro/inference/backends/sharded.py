@@ -39,6 +39,7 @@ from repro.inference.backends.base import (AccountingMixin, BackendInfo,
 from repro.inference.backends.bodies import make_step_bodies
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, make_cache
+from repro.telemetry.spans import profiler_span
 
 _SUPPORTED_KINDS = ("attn", "attn_local")
 
@@ -199,12 +200,14 @@ class ShardedBackend(AccountingMixin):
             self._fns[key] = fn
         return fn
 
-    def _call(self, key, fn, args):
-        """Invoke one sharded step and charge tp dispatch streams plus
-        the psum traffic captured at trace time (priced per platform)."""
+    def _call(self, key, fn, args, launch: str):
+        """Invoke one sharded step (in a ``repro.launch.<launch>`` span)
+        and charge tp dispatch streams plus the psum traffic captured at
+        trace time (priced per platform)."""
         mark = len(self._trace_log)
         t0 = time.perf_counter()
-        logits, cache = fn(self.params, *args)
+        with profiler_span("launch." + launch):
+            logits, cache = fn(self.params, *args)
         host = time.perf_counter() - t0
         new = self._trace_log[mark:]
         del self._trace_log[mark:]
@@ -233,21 +236,23 @@ class ShardedBackend(AccountingMixin):
                 return self._prefill_body(params, cache, tokens, slot, plen)
             fn = self._wrapped(key, body, (P(None, None), P()))
         return self._call(key, fn, (cache, tokens,
-                                    jnp.asarray(slot, jnp.int32)))
+                                    jnp.asarray(slot, jnp.int32)),
+                          "prefill_body")
 
     def decode(self, cache, tokens, lengths):
         """One sharded batched decode step (contiguous cache)."""
         key = ("decode",)
         fn = self._fns.get(key) or self._wrapped(
             key, self._decode_body, (P(None, None), P(None)))
-        return self._call(key, fn, (cache, tokens, lengths))
+        return self._call(key, fn, (cache, tokens, lengths), "decode_body")
 
     def prefill_chunk(self, cache, tokens, bt_row, t0_index):
         """Sharded paged prompt-chunk write through a block table."""
         key = ("prefill_chunk", tokens.shape[1])
         fn = self._fns.get(key) or self._wrapped(
             key, self._paged_prefill_body, (P(None, None), P(None), P()))
-        return self._call(key, fn, (cache, tokens, bt_row, t0_index))
+        return self._call(key, fn, (cache, tokens, bt_row, t0_index),
+                          "prefill_chunk")
 
     def paged_decode(self, cache, tokens, lengths, block_tables):
         """One sharded batched decode step over paged KV."""
@@ -255,7 +260,8 @@ class ShardedBackend(AccountingMixin):
         fn = self._fns.get(key) or self._wrapped(
             key, self._paged_decode_body,
             (P(None, None), P(None), P(None, None)))
-        return self._call(key, fn, (cache, tokens, lengths, block_tables))
+        return self._call(key, fn, (cache, tokens, lengths, block_tables),
+                          "decode")
 
     def verify(self, cache, tokens, lengths):
         """Sharded speculative verify (k+1 positions, one forward)."""
@@ -265,7 +271,7 @@ class ShardedBackend(AccountingMixin):
         fn = self._fns.get(key) or self._wrapped(
             key, self._verify_body, (P(None, None), P(None)),
             logits_spec=P(None, None, None))
-        return self._call(key, fn, (cache, tokens, lengths))
+        return self._call(key, fn, (cache, tokens, lengths), "verify_body")
 
     def paged_verify(self, cache, tokens, lengths, block_tables):
         """Paged-cache variant of sharded ``verify``."""
@@ -274,7 +280,8 @@ class ShardedBackend(AccountingMixin):
             key, self._paged_verify_body,
             (P(None, None), P(None), P(None, None)),
             logits_spec=P(None, None, None))
-        return self._call(key, fn, (cache, tokens, lengths, block_tables))
+        return self._call(key, fn, (cache, tokens, lengths, block_tables),
+                          "paged_verify_body")
 
     # ------------------------------------------------------- accounting
     @property

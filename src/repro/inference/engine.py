@@ -50,6 +50,7 @@ from repro.inference.speculative import (default_draft_config,
                                          validate_draft)
 from repro.telemetry.metrics import RequestTiming
 from repro.telemetry.registry import MetricsRegistry
+from repro.telemetry.spans import profiler_span, profiling
 
 PLAN_STRATEGIES = ("jit", "eager", "whole_graph", "chain", "auto", "fused",
                    "autotuned")
@@ -477,6 +478,8 @@ class ServeEngine:
         self._pending: list = []            # submitted, not yet admitted
         self._admit_seq = 0                 # victim ordering (youngest first)
         self._last_step_progressed = True
+        self._ticks = 0                     # step_num of the tick span
+        self._first_tokens: list = []       # first tokens of this tick
         self.lengths = np.zeros(max_batch, np.int32)
         self.slots: list[Optional[Request]] = [None] * max_batch
         self.registry = MetricsRegistry()
@@ -504,7 +507,10 @@ class ServeEngine:
             self.monitor = None
         # virtual serving clock (seconds): advances by measured wall time
         # while the engine works, jumps forward over idle gaps so open-loop
-        # arrival schedules don't cost real wall time to honor
+        # arrival schedules don't cost real wall time to honor.  Queue wait
+        # and admit-to-token are wall-clock durations instead (perf_counter
+        # stamps at submit, first admission, first-token sampling and the
+        # tick's end): a live server's requests all arrive at 0 here
         self.now = 0.0
         self._bind_telemetry()
 
@@ -533,8 +539,10 @@ class ServeEngine:
         return None
 
     def _sample(self, logits_row) -> int:
-        """Greedy token choice from one logits row."""
-        return int(jnp.argmax(logits_row))
+        """Greedy token choice from one logits row (the host waits for
+        the device here)."""
+        with profiler_span("sync", what="argmax"):
+            return int(jnp.argmax(logits_row))
 
     def _absorb(self, acct: CallAccount, *, decode: bool) -> None:
         """Fold one backend call's accounting into EngineStats — the one
@@ -596,6 +604,13 @@ class ServeEngine:
             "arrival to first emission, engine clock")
         self._h_itl = reg.histogram(
             "engine_itl_seconds", "inter-token latency")
+        self._h_queue_wait = reg.histogram(
+            "engine_queue_wait_seconds",
+            "submit to first admission, wall clock")
+        self._h_admit_to_token = reg.histogram(
+            "engine_admit_to_token_seconds",
+            "first admission to the end of the tick that returns the "
+            "first token, wall clock")
 
     def _note_step(self, batch: int, dt: float, acct: CallAccount) -> None:
         """One decode step into the step-time histogram and the live
@@ -611,7 +626,10 @@ class ServeEngine:
 
     def _note_first_token(self, req: Request) -> RequestTiming:
         """Record a request's first emission: its RequestTiming plus the
-        TTFT histogram sample."""
+        TTFT histogram sample; the tick that produced it hands it over
+        (``_hand_over_first_tokens``)."""
+        req._t_sampled = time.perf_counter()
+        self._first_tokens.append(req)
         timing = RequestTiming(req.rid, arrival_s=req.arrival_s,
                                first_token_s=self.now)
         timing.token_times_s.append(self.now)
@@ -619,6 +637,39 @@ class ServeEngine:
         if self._h_ttft is not None:
             self._h_ttft.observe(max(0.0, self.now - req.arrival_s))
         return timing
+
+    def _admission(self, req: Request, resume: bool = False):
+        """The ``repro.admit`` span of one admission.  A first admission
+        stamps the wall clock and records the wait since ``submit()``;
+        a resumed one (after preemption) keeps its first stamp."""
+        if resume:
+            return profiler_span("admit", rid=req.rid,
+                                 prompt_len=len(req.prompt), resume=1)
+        t = req._t_admit = time.perf_counter()
+        wait = t - getattr(req, "_t_submit", t)
+        self._h_queue_wait.observe(wait)
+        return profiler_span("admit", rid=req.rid,
+                             prompt_len=len(req.prompt),
+                             queue_wait_ms=wait * 1e3)
+
+    def _hand_over_first_tokens(self) -> None:
+        """End of a tick: the first tokens it produced reach the caller
+        now.  One ``repro.first_token`` span each, with the wall-clock
+        split of the wait: submit to admission, admission to now, and
+        how long the sampled token was held inside the tick."""
+        t = time.perf_counter()
+        for req in self._first_tokens:
+            t_admit = getattr(req, "_t_admit", req._t_sampled)
+            self._h_admit_to_token.observe(t - t_admit)
+            if profiling():
+                wait = t_admit - getattr(req, "_t_submit", t_admit)
+                with profiler_span(
+                        "first_token", rid=req.rid,
+                        queue_wait_ms=wait * 1e3,
+                        admit_to_token_ms=(t - t_admit) * 1e3,
+                        holdback_ms=(t - req._t_sampled) * 1e3):
+                    pass
+        self._first_tokens.clear()
 
     def _note_token(self, timing) -> None:
         """Record a non-first emission: token time plus the ITL sample
@@ -656,15 +707,16 @@ class ServeEngine:
         slot = self._free_slot()
         if slot is None:
             return False
-        bucket = self._bucket(plen)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :plen] = req.prompt
-        t0 = time.perf_counter()
-        logits, self.cache = self.backend.prefill(
-            self.cache, jnp.asarray(toks), slot, plen)
-        acct = self.backend.last
-        self._absorb(acct, decode=False)
-        first = self._sample(logits[0])
+        with self._admission(req):
+            bucket = self._bucket(plen)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :plen] = req.prompt
+            t0 = time.perf_counter()
+            logits, self.cache = self.backend.prefill(
+                self.cache, jnp.asarray(toks), slot, plen)
+            acct = self.backend.last
+            self._absorb(acct, decode=False)
+            first = self._sample(logits[0])
         dt = time.perf_counter() - t0
         t_begin = self.now
         self.now += dt
@@ -707,6 +759,12 @@ class ServeEngine:
         resume = getattr(req, "_resume", None)
         if resume is not None and resume[0] == "host":
             return self._restore_from_host(req, slot, resume[1])
+        with self._admission(req, resume=resume is not None):
+            self._start_prefill(req, slot, resume)
+        return True
+
+    def _start_prefill(self, req: Request, slot: int, resume) -> None:
+        """Seat ``req`` in ``slot`` and queue its (chunked) prefill."""
         toks = list(req.prompt)
         replay = False
         if resume is not None:
@@ -735,7 +793,6 @@ class ServeEngine:
             req=req, slot=slot, toks=toks, pos=shared, replay=replay)
         if self.tracer is not None:
             self.tracer.admit(req.rid, self.now, resume=resume is not None)
-        return True
 
     # bound on live donor candidates tracked per prefix key
     _DONORS_PER_KEY = 4
@@ -829,10 +886,11 @@ class ServeEngine:
         n_blocks = self.offload_tier.stored_blocks(req.rid)
         if not self.kv.pool.can_alloc(n_blocks):
             return False                   # wait for blocks to free
-        host_leaves, n_blocks, nbytes, tax = \
-            self.offload_tier.restore(req.rid)
-        ids = self.kv.pool.alloc(req.rid, n_blocks)
-        self.cache = self.kv.scatter_host(self.cache, ids, host_leaves)
+        with self._admission(req, resume=True):
+            host_leaves, n_blocks, nbytes, tax = \
+                self.offload_tier.restore(req.rid)
+            ids = self.kv.pool.alloc(req.rid, n_blocks)
+            self.cache = self.kv.scatter_host(self.cache, ids, host_leaves)
         self.stats.restore_bytes += nbytes
         self.stats.offload_transfers += max(n_blocks, 1)
         self.stats.modeled_offload_tax_s += tax
@@ -891,8 +949,7 @@ class ServeEngine:
         if self.tracer is not None:
             self.tracer.preempt(req.rid, self.now, mode=req._resume[0],
                                 offload_bytes=nbytes, offload_tax_s=tax)
-        freed = self.kv.pool.free(req.rid)
-        self.cache = self.kv.zero_pages(self.cache, freed)
+        self._free_blocks(req.rid)
         self.slots[slot] = None
         self.lengths[slot] = 0
         req.status = "preempted"
@@ -914,25 +971,34 @@ class ServeEngine:
         pool.ensure(req.rid, n_tokens)
         return True
 
+    def _free_blocks(self, rid) -> None:
+        """Return ``rid``'s blocks to the pool and zero their pages (one
+        ``repro.release`` span)."""
+        with profiler_span("release") as span:
+            freed = self.kv.pool.free(rid)
+            span.set_metadata(blocks=len(freed))
+            self.cache = self.kv.zero_pages(self.cache, freed)
+
     def _release_slot(self, slot: int, req: Request) -> None:
         """Free a finished request's slot, blocks, and host staging."""
         self.slots[slot] = None
         self.lengths[slot] = 0
-        freed = self.kv.pool.free(req.rid)
-        self.cache = self.kv.zero_pages(self.cache, freed)
+        self._free_blocks(req.rid)
         if self.offload_tier is not None:
             self.offload_tier.drop(req.rid)
 
     def _run_prefill_chunk(self, task: _PrefillTask, chunk_len: int) -> None:
         """Write the next ``chunk_len`` prompt tokens of one in-flight
         prefill into the paged cache (one backend call)."""
-        toks = np.asarray([task.toks[task.pos:task.pos + chunk_len]],
-                          np.int32)
-        bt = jnp.asarray(self.kv.table_row(task.req.rid))
-        t0c = jnp.asarray(task.pos, jnp.int32)
-        t_start = time.perf_counter()
-        logits, self.cache = self.backend.prefill_chunk(
-            self.cache, jnp.asarray(toks), bt, t0c)
+        with profiler_span("prefill_chunk", rid=task.req.rid,
+                           tokens=chunk_len, start=task.pos):
+            toks = np.asarray([task.toks[task.pos:task.pos + chunk_len]],
+                              np.int32)
+            bt = jnp.asarray(self.kv.table_row(task.req.rid))
+            t0c = jnp.asarray(task.pos, jnp.int32)
+            t_start = time.perf_counter()
+            logits, self.cache = self.backend.prefill_chunk(
+                self.cache, jnp.asarray(toks), bt, t0c)
         acct = self.backend.last
         self._absorb(acct, decode=False)
         task.last_logits = logits
@@ -996,12 +1062,14 @@ class ServeEngine:
             remaining = len(task.toks) - task.pos
             chunk_len = (remaining if self.prefill_chunk is None
                          else min(self.prefill_chunk, remaining))
-            if not self._ensure_paged_blocks(
-                    task.req, task.pos + chunk_len, exclude=slot):
-                continue            # stalled on blocks; retry next step
-            if not self._cow_protect(task.req.rid, task.pos,
-                                     task.pos + chunk_len):
-                continue            # stalled on a CoW copy block
+            with profiler_span("prepare"):
+                # stalled on blocks or on a CoW copy block: retry next step
+                ready = self._ensure_paged_blocks(
+                    task.req, task.pos + chunk_len, exclude=slot) and \
+                    self._cow_protect(task.req.rid, task.pos,
+                                      task.pos + chunk_len)
+            if not ready:
+                continue
             self._run_prefill_chunk(task, chunk_len)
             progressed = True
             if task.pos >= len(task.toks):
@@ -1012,10 +1080,74 @@ class ServeEngine:
         """One paged decode round: grow block tables (preempting if the
         pool is exhausted), step ready rows, advance chunked prefills.
         Returns False when nothing could progress."""
+        with profiler_span("decode") as span:
+            with profiler_span("prepare"):
+                active = self._prepare_paged_rows()
+                if not active:
+                    return False
+                toks = np.zeros((self.B, 1), np.int32)
+                for i in active:
+                    toks[i, 0] = self.slots[i].generated[-1]
+                owners = [self.slots[i].rid
+                          if self.slots[i] is not None
+                          and i not in self._prefill_tasks else None
+                          for i in range(self.B)]
+                bt = jnp.asarray(self.kv.block_tables(owners))
+                toks, lengths = jnp.asarray(toks), jnp.asarray(self.lengths)
+            if profiling():
+                span.set_metadata(rows=len(active), live_tokens=int(
+                    self.lengths[active].sum()) + len(active))
+            t0 = time.perf_counter()
+            logits, self.cache = self.backend.paged_decode(
+                self.cache, toks, lengths, bt)
+            acct = self.backend.last
+            self._absorb(acct, decode=True)
+            self.stats.decode_steps += 1
+            self.stats.slot_occupancy.append(len(active))
+            self.stats.block_pool_utilization.append(
+                self.kv.pool.utilization)
+            with profiler_span("sync", what="logits"):
+                logits_np = np.asarray(logits)
+            dt = time.perf_counter() - t0
+            t_begin = self.now
+            self.now += dt
+            self.stats.step_times_s.append(dt)
+            self._note_step(len(active), dt, acct)
+            if self.tracer is not None:
+                self.tracer.decode([self.slots[i].rid for i in active],
+                                   t_begin, self.now, tax_s=acct.host_time_s,
+                                   batch=len(active),
+                                   modeled_tklqt_s=acct.modeled_tklqt_s)
+            if self.telemetry is not None:
+                self.telemetry.add(f"decode[b={len(active)}]", "decode",
+                                   t_begin, self.now, batch=len(active))
+                self._record_segments(acct, t_begin)
+            for i in active:
+                req = self.slots[i]
+                self.lengths[i] += 1
+                nxt = int(np.argmax(logits_np[i]))
+                req.generated.append(nxt)
+                self.stats.tokens_out += 1
+                timing = self.timings.get(req.rid)
+                self._note_token(timing)
+                if len(req.generated) >= req.max_new_tokens or \
+                        self.lengths[i] >= self.T - 1:
+                    req.done = True
+                    req.status = "done"
+                    if timing is not None:
+                        timing.done_s = self.now
+                    self._release_slot(i, req)
+                    if self.tracer is not None:
+                        self.tracer.done(req.rid, self.now,
+                                         n_tokens=len(req.generated))
+        return True
+
+    def _prepare_paged_rows(self) -> list:
+        """Grow every decode row's table to cover the entry this step
+        writes; growth may preempt younger rows out of this very step.
+        Returns the rows that can step."""
         active = [i for i, s in enumerate(self.slots)
                   if s is not None and i not in self._prefill_tasks]
-        # grow every row's table to cover the entry this step writes;
-        # growth may preempt younger rows out of this very step
         stalled = set()
         for i in active:
             if self.slots[i] is None:
@@ -1031,60 +1163,8 @@ class ServeEngine:
                                        int(self.lengths[i]),
                                        int(self.lengths[i]) + 1):
                 stalled.add(i)
-        active = [i for i in active
-                  if self.slots[i] is not None and i not in stalled]
-        if not active:
-            return False
-        toks = np.zeros((self.B, 1), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].generated[-1]
-        owners = [self.slots[i].rid
-                  if self.slots[i] is not None
-                  and i not in self._prefill_tasks else None
-                  for i in range(self.B)]
-        bt = jnp.asarray(self.kv.block_tables(owners))
-        t0 = time.perf_counter()
-        logits, self.cache = self.backend.paged_decode(
-            self.cache, jnp.asarray(toks), jnp.asarray(self.lengths), bt)
-        acct = self.backend.last
-        self._absorb(acct, decode=True)
-        self.stats.decode_steps += 1
-        self.stats.slot_occupancy.append(len(active))
-        self.stats.block_pool_utilization.append(self.kv.pool.utilization)
-        logits_np = np.asarray(logits)
-        dt = time.perf_counter() - t0
-        t_begin = self.now
-        self.now += dt
-        self.stats.step_times_s.append(dt)
-        self._note_step(len(active), dt, acct)
-        if self.tracer is not None:
-            self.tracer.decode([self.slots[i].rid for i in active],
-                               t_begin, self.now, tax_s=acct.host_time_s,
-                               batch=len(active),
-                               modeled_tklqt_s=acct.modeled_tklqt_s)
-        if self.telemetry is not None:
-            self.telemetry.add(f"decode[b={len(active)}]", "decode",
-                               t_begin, self.now, batch=len(active))
-            self._record_segments(acct, t_begin)
-        for i in active:
-            req = self.slots[i]
-            self.lengths[i] += 1
-            nxt = int(np.argmax(logits_np[i]))
-            req.generated.append(nxt)
-            self.stats.tokens_out += 1
-            timing = self.timings.get(req.rid)
-            self._note_token(timing)
-            if len(req.generated) >= req.max_new_tokens or \
-                    self.lengths[i] >= self.T - 1:
-                req.done = True
-                req.status = "done"
-                if timing is not None:
-                    timing.done_s = self.now
-                self._release_slot(i, req)
-                if self.tracer is not None:
-                    self.tracer.done(req.rid, self.now,
-                                     n_tokens=len(req.generated))
-        return True
+        return [i for i in active
+                if self.slots[i] is not None and i not in stalled]
 
     # ------------------------------------------------------------ speculative
     def _draft_prefill_slot(self, slot: int, toks_list) -> None:
@@ -1285,17 +1365,19 @@ class ServeEngine:
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return
-        toks = np.zeros((self.B, 1), np.int32)
-        for i in active:
-            toks[i, 0] = self.slots[i].generated[-1]
-        t0 = time.perf_counter()
-        logits, self.cache = self.backend.decode(
-            self.cache, jnp.asarray(toks), jnp.asarray(self.lengths))
-        acct = self.backend.last
-        self._absorb(acct, decode=True)
-        self.stats.decode_steps += 1
-        self.stats.slot_occupancy.append(len(active))
-        logits_np = np.asarray(logits)
+        with profiler_span("decode", rows=len(active)):
+            toks = np.zeros((self.B, 1), np.int32)
+            for i in active:
+                toks[i, 0] = self.slots[i].generated[-1]
+            t0 = time.perf_counter()
+            logits, self.cache = self.backend.decode(
+                self.cache, jnp.asarray(toks), jnp.asarray(self.lengths))
+            acct = self.backend.last
+            self._absorb(acct, decode=True)
+            self.stats.decode_steps += 1
+            self.stats.slot_occupancy.append(len(active))
+            with profiler_span("sync", what="logits"):
+                logits_np = np.asarray(logits)
         dt = time.perf_counter() - t0
         t_begin = self.now
         self.now += dt
@@ -1339,6 +1421,7 @@ class ServeEngine:
         This is the entry point an external router uses to feed a replica
         incrementally — ``run()`` is submit-everything-then-drain.
         """
+        req._t_submit = time.perf_counter()
         if self.tracer is not None:
             # idempotent: a router-fed replica already minted this trace
             # at fleet ingress; engine-only runs mint it here
@@ -1385,24 +1468,43 @@ class ServeEngine:
         """
         if not self.busy:
             return False
+        self._ticks += 1
+        with jax.profiler.StepTraceAnnotation("repro.tick",
+                                              step_num=self._ticks) as span:
+            traced = profiling()
+            if traced:
+                chunks, steps = self.stats.prefill_chunks, \
+                    self.stats.decode_steps
+            admitted = self._tick()
+            self._hand_over_first_tokens()
+            if traced:
+                span.set_metadata(
+                    decode_rows=(self.stats.slot_occupancy[-1]
+                                 if self.stats.decode_steps > steps else 0),
+                    prefill_chunks=self.stats.prefill_chunks - chunks,
+                    admitted=admitted)
+        return True
+
+    def _tick(self) -> int:
+        """The body of ``tick()``; returns the number of admissions."""
         idle = not any(s is not None for s in self.slots) \
             and not self._preempted
         if idle and self._pending and \
                 self._pending[0].arrival_s > self.now:
             self.now = self._pending[0].arrival_s
-        admitted = False
+        admitted = 0
         # resumed requests first: they hold generation progress (and
         # possibly offloaded KV) — finishing them frees blocks fastest
         while self._preempted and self._free_slot() is not None:
             if not self._admit_paged(self._preempted[0]):
                 break               # no blocks to restore into yet
             self._preempted.pop(0)
-            admitted = True
+            admitted += 1
         while (self._pending and self._pending[0].arrival_s <= self.now
                and self._free_slot() is not None):
             if self.admit(self._pending[0]):
                 self._pending.pop(0)
-                admitted = True
+                admitted += 1
             else:
                 break
         self.step()
@@ -1417,7 +1519,7 @@ class ServeEngine:
                 f"({self.kv.num_blocks} x {self.kv.block_size} tokens) "
                 "too small for even one in-flight request; raise "
                 "num_blocks")
-        return True
+        return admitted
 
     def run(self, requests: list[Request]) -> list[Request]:
         """Continuous batching: admit whenever a slot frees.
@@ -1459,6 +1561,7 @@ class ServeEngine:
             self.draft_cache = jax.tree.map(jnp.zeros_like, self.draft_cache)
             self.draft_lengths = np.zeros(self.B, np.int32)
         self._pending = []
+        self._first_tokens = []
         if self.cache_mode == "paged":
             self.kv.reset()
             self._prefill_tasks = {}

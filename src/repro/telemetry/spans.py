@@ -7,6 +7,11 @@ small object, a disabled recorder costs one attribute check, and nothing
 is aggregated until a report or export asks for it.  Timestamps are
 whatever clock the caller stamps with (the engine uses its virtual
 serving clock so idle fast-forwards don't appear as giant gaps).
+
+``profiler_span`` is the other kind: a ``repro.<name>`` annotation that
+``jax.profiler`` records on its own clock, beside the device's programs
+and operations, while a trace is being captured (and costs well under a
+microsecond when none is).
 """
 from __future__ import annotations
 
@@ -15,10 +20,23 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 # chrome-trace thread ids for the merged timeline
 TID_HOST = 0          # engine-level host work (prefill/decode dispatch)
 TID_SEGMENTS = 1      # per-segment launches inside PlanExecutor
 TID_DEVICE = 2        # modeled device lane (simulated kernels)
+
+PROFILER_PREFIX = "repro."
+# True while a jax.profiler trace is being captured: guards span arguments
+# that cost more than reading a field
+profiling = TraceAnnotation.is_enabled
+
+
+def profiler_span(name: str, **args) -> TraceAnnotation:
+    """A ``repro.<name>`` span on the profiler's clock, with ``args`` as
+    its stats; ``set_metadata`` on the returned context adds more."""
+    return TraceAnnotation(PROFILER_PREFIX + name, **args)
 
 
 @dataclass

@@ -1,5 +1,4 @@
-"""Pipeline parallelism, int8 KV cache, and trace export."""
-import json
+"""Pipeline parallelism and the int8 KV cache."""
 import subprocess
 import sys
 import textwrap
@@ -9,9 +8,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.device_model import PLATFORMS, simulate
-from repro.core.export import to_chrome_trace
-from repro.core.tracing import Kernel
 from repro.inference.kv_quant import (
     make_quantized_cache, read_kv, write_kv)
 
@@ -117,19 +113,3 @@ def test_kv_quant_attention_close_to_fp():
     assert float(jnp.max(jnp.abs(o_q - o_f))) < 0.05
 
 
-# ------------------------------------------------------------ export
-def test_chrome_trace_export(tmp_path):
-    ks = [Kernel(i, f"k{i}", None, 1e6, 1e5, ()) for i in range(5)]
-    ev = simulate(ks, PLATFORMS["GH200"])
-    doc = to_chrome_trace(ev, "GH200")
-    # host + kernel slice plus an s/f flow pair per kernel
-    assert len(doc["traceEvents"]) == 20
-    host = [e for e in doc["traceEvents"]
-            if e["tid"] == 0 and e["ph"] == "X"]
-    dev = [e for e in doc["traceEvents"]
-           if e["tid"] == 1 and e["ph"] == "X"]
-    assert len(host) == len(dev) == 5
-    # device events never start before their launch call
-    for h, d in zip(host, dev):
-        assert d["ts"] >= h["ts"]
-    json.dumps(doc)                  # serializable

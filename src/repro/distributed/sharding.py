@@ -205,20 +205,17 @@ def paged_cache_specs(pages, cfg: ModelConfig, mesh: Mesh,
     """Specs for the block-table paged pages pytree (``make_paged_cache``).
 
     Page pools are global (shared across batch rows through block tables),
-    so there is no batch axis to put ``data`` on; the KV-head axis shards
-    over ``tp`` exactly like the contiguous cache — leaves are
-    ``k_pages``/``v_pages`` shaped (n_sb, P, bs, HKV, hd), plus
-    ``k_scale``/``v_scale`` (n_sb, P, bs, HKV) when the pool is quantized
-    (the per-(token, head) scales shard on the same head axis as their
-    payload).  Indivisible head counts fall back to replication
-    (divisibility handled by ``valid_spec``)."""
+    so there is no batch axis to put ``data`` on.  Every leaf —
+    ``k_pages``/``v_pages`` (n_sb, G, P, bs·(HKV/G)·hd) and, when the pool
+    is quantized, ``k_scale``/``v_scale`` (n_sb, G, P, bs·(HKV/G)) — shards
+    its head-group axis G over ``tp``: built with G = tp, each device holds
+    whole heads, and inside ``shard_map`` its block is a valid pool of the
+    per-device config.  A G that ``tp`` does not divide falls back to
+    replication (``valid_spec``)."""
     def spec_for(path, leaf):
         name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
-        if name in ("k_pages", "v_pages"):
-            return valid_spec(leaf.shape, P(None, None, None, tp, None),
-                              mesh)
-        if name in ("k_scale", "v_scale"):
-            return valid_spec(leaf.shape, P(None, None, None, tp), mesh)
+        if name in ("k_pages", "v_pages", "k_scale", "v_scale"):
+            return valid_spec(leaf.shape, P(None, tp, None, None), mesh)
         return valid_spec(leaf.shape, P(*(None,) * len(leaf.shape)), mesh)
 
     flat = jax.tree_util.tree_flatten_with_path(pages)[0]

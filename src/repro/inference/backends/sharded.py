@@ -176,10 +176,14 @@ class ShardedBackend(AccountingMixin):
         return _jit_into(make, self._cache_spec_tree, self.mesh)
 
     def init_paged_cache(self, kv):
-        """Head-sharded pooled KV pages built on the tp mesh."""
+        """Pooled KV pages in tp whole-head groups, one group per device
+        of the tp mesh."""
+        def make():
+            return kv.make_pages(groups=self.tp)
+
         self._cache_spec_tree = paged_cache_specs(
-            jax.eval_shape(kv.make_pages), self.cfg, self.mesh, tp="model")
-        return _jit_into(kv.make_pages, self._cache_spec_tree, self.mesh)
+            jax.eval_shape(make), self.cfg, self.mesh, tp="model")
+        return _jit_into(make, self._cache_spec_tree, self.mesh)
 
     # ------------------------------------------------------------ dispatch
     def _wrapped(self, key, body, arg_specs, logits_spec=P(None, None)):

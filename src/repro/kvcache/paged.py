@@ -3,8 +3,9 @@
 ``PagedKVCache`` owns the geometry (block size, pool size, blocks per
 slot) and the ``BlockPool`` allocator; the device pages themselves are a
 plain cache pytree (``models.make_paged_cache`` — leaves shaped
-``(n_superblocks, P, bs, HKV, hd)``) that the engine threads through
-``forward`` functionally.  Methods that touch pages take and return the
+``(n_superblocks, G, P, row)``: one lane-dense row per layer, head group
+and page) that the engine threads through ``forward`` functionally.  The
+page axis is axis 2 of every leaf.  Methods that touch pages take and return the
 pytree rather than mutating hidden state, so jit boundaries stay clean.
 """
 from __future__ import annotations
@@ -18,6 +19,37 @@ import numpy as np
 from repro.inference.kv_quant import KV_DTYPES
 from repro.kvcache.allocator import BlockPool
 from repro.models import make_paged_cache
+
+
+def _rows(leaf, idx):
+    """Indices of pages ``idx``' rows in every layer and head group of a
+    pool leaf: ``leaf[_rows(leaf, idx)]`` is (L, G, len(idx), row).
+    Indexing all three leading axes leaves the row alone as the window, so
+    on the TPU a scatter writes the rows in place; a page index on axis 2
+    alone makes XLA relayout the whole pool around the scatter."""
+    n_l, n_g = leaf.shape[:2]
+    return (jnp.arange(n_l)[:, None, None], jnp.arange(n_g)[None, :, None],
+            idx[None, None, :])
+
+
+@jax.jit
+def _zero_rows(leaf, idx):
+    """``leaf`` with pages ``idx`` zeroed: one program per leaf shape and
+    count.  Leaf by leaf, only one leaf's new copy is allocated at a time
+    beside the pool (the pool is not donated)."""
+    return leaf.at[_rows(leaf, idx)].set(0)
+
+
+@jax.jit
+def _take_rows(leaf, idx):
+    """Pages ``idx``' rows of ``leaf``: (L, G, len(idx), row)."""
+    return leaf[_rows(leaf, idx)]
+
+
+@jax.jit
+def _put_rows(leaf, idx, rows):
+    """``leaf`` with pages ``idx`` set to ``rows`` (L, G, len(idx), row)."""
+    return leaf.at[_rows(leaf, idx)].set(rows.astype(leaf.dtype))
 
 
 class PagedKVCache:
@@ -48,12 +80,14 @@ class PagedKVCache:
     def sentinel(self) -> int:
         return self.num_blocks
 
-    def make_pages(self):
+    def make_pages(self, groups: int = 1):
         """Fresh zeroed pages pytree for ``forward`` (quantized layout when
-        ``kv_dtype="int8"``).  Stamps the pool with the per-block byte
-        size so ``kv_bytes_saved`` prices shared blocks correctly."""
+        ``kv_dtype="int8"``; ``groups`` whole-head groups for tensor
+        parallelism).  Stamps the pool with the per-block byte size so
+        ``kv_bytes_saved`` prices shared blocks correctly."""
         pages = make_paged_cache(self.cfg, self.num_blocks, self.block_size,
-                                 self.dtype, kv_dtype=self.kv_dtype)
+                                 self.dtype, kv_dtype=self.kv_dtype,
+                                 groups=groups)
         self.pool.block_bytes = self.block_bytes(pages, 1)
         return pages
 
@@ -77,35 +111,38 @@ class PagedKVCache:
         if not ids:
             return pages
         idx = jnp.asarray(ids, jnp.int32)
-        return jax.tree.map(lambda p: p.at[:, idx].set(0), pages)
+        return jax.tree.map(lambda p: _zero_rows(p, idx), pages)
 
     def gather_host(self, pages, ids: list) -> list:
         """Copy ``ids``' page contents device->host (the offload DMA);
         returns one np.ndarray per cache leaf, in jax.tree.leaves order."""
         idx = jnp.asarray(ids, jnp.int32)
-        return [np.asarray(leaf[:, idx]) for leaf in jax.tree.leaves(pages)]
+        return [np.asarray(_take_rows(leaf, idx))
+                for leaf in jax.tree.leaves(pages)]
 
     def scatter_host(self, pages, ids: list, host_leaves: list):
         """Copy host->device into freshly allocated pages (the restore)."""
         idx = jnp.asarray(ids, jnp.int32)
         leaves, treedef = jax.tree.flatten(pages)
-        new = [leaf.at[:, idx].set(jnp.asarray(h).astype(leaf.dtype))
-               for leaf, h in zip(leaves, host_leaves)]
-        return jax.tree.unflatten(treedef, new)
+        return jax.tree.unflatten(treedef, [
+            _put_rows(leaf, idx, jnp.asarray(h))
+            for leaf, h in zip(leaves, host_leaves)])
 
     def copy_pages(self, pages, src_id: int, dst_id: int):
         """Copy-on-write divergence: duplicate block ``src_id``'s page
         contents into freshly allocated block ``dst_id`` across every
         cache leaf, so the subsequent write lands on a private copy."""
-        return jax.tree.map(lambda p: p.at[:, dst_id].set(p[:, src_id]),
-                            pages)
+        src = jnp.asarray([src_id], jnp.int32)
+        dst = jnp.asarray([dst_id], jnp.int32)
+        return jax.tree.map(
+            lambda p: _put_rows(p, dst, _take_rows(p, src)), pages)
 
     def block_bytes(self, pages, n_blocks: int = 1) -> int:
         """Bytes of KV held by ``n_blocks`` pool blocks across all layers."""
         total = 0
         for leaf in jax.tree.leaves(pages):
             per_block = leaf.dtype.itemsize * int(np.prod(
-                (leaf.shape[0],) + leaf.shape[2:]))
+                leaf.shape[:2] + leaf.shape[3:]))
             total += per_block * n_blocks
         return total
 

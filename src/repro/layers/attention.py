@@ -145,58 +145,108 @@ def attention_core(q, k, v, **kw):
     return mha(q, k, v, **kw)
 
 
-def _paged_attention_fwd(q, k, v, cache, block_tables, positions, lengths,
-                         cache_index, cfg: ModelConfig, *,
-                         causal, window, scale):
-    """Self-attention over the block-table paged KV cache.
+def _touched_pages(block_tables, positions, bs: int, n_pages: int):
+    """The page rows a call writes, and where each new token lands in them.
 
-    Pages are pool-global — k_pages/v_pages: (P, bs, HKV, hd) — and
-    ``block_tables`` (B, NB) maps a row's logical token position ``t`` to
-    page ``bt[b, t // bs]``.  New K/V rows are scattered at their positions
-    (out-of-range table entries — the pool's pad sentinel — drop the
-    write), then each row's logical view is gathered back for the masked
-    attention core: the pure-XLA analogue of
-    ``repro.kernels.decode_attention.paged_decode_attention``.
+    A row's S new tokens sit at consecutive positions ``positions[b, 0]``
+    onwards (as ``forward`` builds them), so they cover at most
+    J = (S + 2·bs − 2) // bs consecutive logical blocks — one for a decode
+    step, C/bs + 1 for a C-token chunk that may start mid-page.  Returns
+    ``pages`` (B, J), the pool page of each covered block, and ``src``
+    (B, J, bs), the index into the S new tokens of each token slot of that
+    page (−1: the slot keeps what it holds).  A block past the row's table
+    (a verify window crossing ``max_len``), a sentinel table entry, or a
+    block no new token lands in gets page ``n_pages``, whose write drops.
+    """
+    s = positions.shape[1]
+    nb = block_tables.shape[1]
+    n_touch = (s + 2 * bs - 2) // bs
+    blk = positions[:, :1] // bs + jnp.arange(n_touch, dtype=jnp.int32)
+    src = (blk[..., None] * bs + jnp.arange(bs, dtype=jnp.int32)
+           - positions[:, :1, None])
+    hit = (src >= 0) & (src < s)
+    pages = jnp.take_along_axis(block_tables, jnp.minimum(blk, nb - 1),
+                                axis=1)
+    pages = jnp.where((blk < nb) & hit.any(-1), pages, n_pages)
+    return pages, jnp.where(hit, src, -1)
+
+
+def _write_rows(pool, layer, pages, src, new):
+    """Whole-row read-modify-write of layer ``layer`` of one pool leaf.
+
+    pool: (L, G, P, bs·(H/G)·w) — per layer, head group and page one row,
+    token-major; new: (B, S, H, w) values of the S new tokens (w = hd for
+    K/V payloads, 1 for int8 scales).  The touched rows are gathered, the
+    new tokens merged in with a select, and the rows scattered back whole;
+    out-of-range pages (``_touched_pages``' sentinel) drop.  Per-element
+    scatters would make XLA relayout the whole pool on the TPU.
+    """
+    n_groups, n_pages = pool.shape[1], pool.shape[2]
+    b, _, h, w = new.shape
+    n_touch, bs = src.shape[1], src.shape[2]
+    g = jnp.arange(n_groups)[:, None, None]
+    old = pool[layer, g, jnp.minimum(pages, n_pages - 1)[None]]
+    old = old.reshape(n_groups, b, n_touch, bs, -1)
+    tok = new[jnp.arange(b)[:, None], jnp.maximum(src, 0).reshape(b, -1)]
+    tok = tok.reshape(b, n_touch, bs, n_groups, (h // n_groups) * w)
+    tok = tok.transpose(3, 0, 1, 2, 4).astype(pool.dtype)
+    rows = jnp.where((src >= 0)[None, ..., None], tok, old)
+    return pool.at[layer, g, pages[None]].set(
+        rows.reshape(n_groups, b, n_touch, -1), mode="drop")
+
+
+def _read_rows(pool, layer, safe, h, w):
+    """Every row's whole table of layer ``layer``, as (B, NB·bs, H, w)."""
+    n_groups = pool.shape[1]
+    b, nb = safe.shape
+    g = jnp.arange(n_groups)[:, None, None]
+    rows = pool[layer, g, safe[None]]                   # (G, B, NB, row)
+    rows = rows.reshape(n_groups, b, nb, -1, h // n_groups, w)
+    return rows.transpose(1, 2, 3, 0, 4, 5).reshape(b, -1, h, w)
+
+
+def _paged_attention_fwd(q, k, v, cache, layer, block_tables, positions,
+                         lengths, cache_index, cfg: ModelConfig, *,
+                         causal, window, scale):
+    """Self-attention of layer ``layer`` over the block-table paged pool.
+
+    ``cache`` holds the whole stack's pool (``make_paged_self_cache``
+    stacked over layers): k_pages/v_pages (L, G, P, bs·(HKV/G)·hd), one
+    lane-dense row per (layer, head group, page), token-major within the
+    row.  ``block_tables`` (B, NB) maps a row's logical token position ``t``
+    to page ``bt[b, t // bs]``.  The new K/V entries are written as whole
+    page rows (``_write_rows``), then each row's logical view is gathered
+    back for the masked attention core: the pure-XLA analogue of
+    ``repro.kernels.decode_attention.paged_decode_attention``.  Returns the
+    attention output and the pool with layer ``layer`` updated.
     """
     assert block_tables is not None, "paged KV cache needs block_tables"
-    b, s = q.shape[0], q.shape[1]
-    kp, vp = cache["k_pages"], cache["v_pages"]
-    n_pages, bs_blk = kp.shape[0], kp.shape[1]
-    quantized = "k_scale" in cache
-    blk = positions // bs_blk
-    nb = block_tables.shape[1]
-    pages = jnp.take_along_axis(block_tables, jnp.minimum(blk, nb - 1), axis=1)
-    # positions past the slot's table (a verify window crossing max_len)
-    # must DROP, not clamp onto the last real page
-    pages = jnp.where(blk < nb, pages, n_pages)
-    offs = positions % bs_blk
-    if quantized:
+    b, s, hkv, hd = k.shape
+    kp = cache["k_pages"]
+    n_pages = kp.shape[2]
+    bs_blk = kp.shape[3] * kp.shape[1] // (hkv * hd)
+    pages, src = _touched_pages(block_tables, positions, bs_blk, n_pages)
+    if "k_scale" in cache:
         # quantize-on-write: only the int8 payload + per-(token,head) f32
         # scale ever live in the pool; the bf16 intermediate is transient
-        qk, sk = quantize_kv(k)
-        qv, sv = quantize_kv(v)
-        kp = kp.at[pages, offs].set(qk, mode="drop")
-        vp = vp.at[pages, offs].set(qv, mode="drop")
-        ksp = cache["k_scale"].at[pages, offs].set(sk, mode="drop")
-        vsp = cache["v_scale"].at[pages, offs].set(sv, mode="drop")
-        new_cache = {"k_pages": kp, "v_pages": vp,
-                     "k_scale": ksp, "v_scale": vsp}
+        (qk, sk), (qv, sv) = quantize_kv(k), quantize_kv(v)
+        new = {"k_pages": qk, "v_pages": qv,
+               "k_scale": sk[..., None], "v_scale": sv[..., None]}
     else:
-        kp = kp.at[pages, offs].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[pages, offs].set(v.astype(vp.dtype), mode="drop")
-        new_cache = {"k_pages": kp, "v_pages": vp}
+        new = {"k_pages": k, "v_pages": v}
+    new_cache = {name: _write_rows(cache[name], layer, pages, src, x)
+                 for name, x in new.items()}
     safe = jnp.clip(block_tables, 0, n_pages - 1)
-    t = block_tables.shape[1] * bs_blk
-    if quantized:
+    kg, vg = (_read_rows(new_cache[n], layer, safe, hkv, hd)
+              for n in ("k_pages", "v_pages"))
+    if "k_scale" in cache:
         # dequantize-at-load: gather int8 pages + scales, widen to the
         # compute dtype only in the transient logical view
-        kg = dequantize_kv(kp[safe], ksp[safe], k.dtype)
-        vg = dequantize_kv(vp[safe], vsp[safe], v.dtype)
-        kg = kg.reshape(b, t, kp.shape[2], kp.shape[3])
-        vg = vg.reshape(b, t, vp.shape[2], vp.shape[3])
-    else:
-        kg = kp[safe].reshape(b, t, kp.shape[2], kp.shape[3])
-        vg = vp[safe].reshape(b, t, vp.shape[2], vp.shape[3])
+        kg = dequantize_kv(kg, _read_rows(new_cache["k_scale"], layer, safe,
+                                          hkv, 1)[..., 0], k.dtype)
+        vg = dequantize_kv(vg, _read_rows(new_cache["v_scale"], layer, safe,
+                                          hkv, 1)[..., 0], v.dtype)
+    t = kg.shape[1]
     kv_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     if lengths is not None:
         # continuous-batching decode / speculative verify: row b just wrote
@@ -229,6 +279,7 @@ def attention_fwd(
     shd=None,                               # sharding hook (head-parallel attn)
     block_tables: Optional[jax.Array] = None,  # (B,NB) page ids (paged cache)
     reduce=None,                            # TP output hook (psum in shard_map)
+    layer=None,                             # paged: this layer's pool index
 ):
     """Returns (out (B,S,d), new_cache|None).
 
@@ -278,7 +329,7 @@ def attention_fwd(
         k = apply_rope(k, positions, cfg.rope_theta)
         if cache is not None and "k_pages" in cache:
             out, new_cache = _paged_attention_fwd(
-                q, k, v, cache, block_tables, positions, lengths,
+                q, k, v, cache, layer, block_tables, positions, lengths,
                 cache_index, cfg, causal=causal, window=window, scale=scale)
             return finish(out), new_cache
         if shd is not None:
@@ -342,21 +393,31 @@ def make_self_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
 
 
 def make_paged_self_cache(cfg: ModelConfig, num_pages: int, block_size: int,
-                          dtype, quantized: bool = False):
+                          dtype, quantized: bool = False, groups: int = 1):
     """Pool-global paged KV: pages are shared by all slots via block tables
     (``repro.kvcache``) rather than pre-carved per batch row.
 
-    ``quantized``: int8 payload pages plus per-(token, head) f32 scale
-    pages (``inference.kv_quant`` layout) — hd bytes + 4 scale bytes per
-    (token, head) instead of 2*hd, so the same pool bytes hold
-    ~2*hd/(hd+4) more tokens.
+    Each page is one lane-dense row per head group: leaves are
+    (groups, P, bs·(HKV/groups)·hd), token-major within the row, so the
+    TPU keeps the default layout with no padding.  ``groups`` > 1 splits
+    the KV heads into that many whole-head groups, the axis tensor
+    parallelism shards (``distributed.sharding.paged_cache_specs``).
+
+    ``quantized``: int8 payload rows plus per-(token, head) f32 scale rows
+    (G, P, bs·(HKV/groups)) (``inference.kv_quant`` numerics) — hd bytes
+    + 4 scale bytes per (token, head) instead of 2*hd, so the same pool
+    bytes hold ~2*hd/(hd+4) more tokens.
     """
-    shape = (num_pages, block_size, cfg.n_kv_heads, cfg.hd)
+    if cfg.n_kv_heads % groups:
+        raise ValueError(f"groups={groups} must divide "
+                         f"n_kv_heads={cfg.n_kv_heads}")
+    row = block_size * (cfg.n_kv_heads // groups)
+    shape = (groups, num_pages, row * cfg.hd)
     if quantized:
         return {"k_pages": jnp.zeros(shape, jnp.int8),
                 "v_pages": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1], jnp.float32)}
+                "k_scale": jnp.zeros(shape[:-1] + (row,), jnp.float32),
+                "v_scale": jnp.zeros(shape[:-1] + (row,), jnp.float32)}
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 

@@ -15,6 +15,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
@@ -126,15 +127,21 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def make_paged_cache(cfg: ModelConfig, num_pages: int, block_size: int,
-                     dtype=None, kv_dtype: str = "bf16"):
+                     dtype=None, kv_dtype: str = "bf16", groups: int = 1):
     """Stacked (over superblocks) PAGED decode cache: per attention slot a
     pool of ``num_pages`` fixed-size token pages shared across batch rows
     through block tables (``forward(..., block_tables=...)``).  Only
     pure-attention stacks page — recurrent state (mamba/rwkv) is O(1) per
     slot and has nothing to page.
 
+    Leaves are (n_superblocks, groups, P, row): one lane-dense row per
+    layer, head group and page (``attn.make_paged_self_cache``).
+    ``forward`` carries the pool whole through the layer loop and each
+    layer reads and writes its own rows in place.  ``groups`` is 1 unless
+    tensor parallelism shards the heads (``ShardedBackend``).
+
     ``kv_dtype="int8"`` swaps each slot's pages for the quantized layout
-    (int8 payload + per-(token, head) f32 scale pages); ``forward``
+    (int8 payload + per-(token, head) f32 scale rows); ``forward``
     dispatches on the ``k_scale`` leaf, so callers thread the pytree
     through unchanged."""
     dtype = dtype or cfg.cdtype
@@ -148,7 +155,7 @@ def make_paged_cache(cfg: ModelConfig, num_pages: int, block_size: int,
         raise ValueError("paged KV cache does not support enc-dec models")
     per_sb = {f"slot{i}": {"self": attn.make_paged_self_cache(
                   cfg, num_pages, block_size, dtype,
-                  quantized=(kv_dtype == "int8"))}
+                  quantized=(kv_dtype == "int8"), groups=groups)}
               for i, kind in enumerate(cfg.block_pattern)}
     n = cfg.n_superblocks
     return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
@@ -166,7 +173,7 @@ _MIXER_SCOPE = {"attn": "attn", "attn_local": "attn", "xattn": "xattn",
 def _apply_slot(bp, x, cfg: ModelConfig, kind: str, slot: int, *,
                 positions, causal, cache, cache_index, encoder_out,
                 dist, shd, aux, lengths=None, block_tables=None,
-                reduce=None):
+                reduce=None, layer=None):
     with jax.named_scope("norm1"):
         h = rmsnorm(x, bp["norm1"]["scale"], cfg.norm_eps)
     new_cache = dict(cache) if cache is not None else None
@@ -180,7 +187,8 @@ def _apply_slot(bp, x, cfg: ModelConfig, kind: str, slot: int, *,
                 cache=None if cache is None else cache.get("self"),
                 cache_index=cache_index, lengths=lengths,
                 block_tables=block_tables,
-                shd=None if shd is _id_shard else shd, reduce=reduce)
+                shd=None if shd is _id_shard else shd, reduce=reduce,
+                layer=layer)
             if nc is not None:
                 new_cache["self"] = nc
         elif kind == "xattn":
@@ -259,9 +267,7 @@ def _run_stack(blocks, x, cfg: ModelConfig, pattern, *, positions, causal,
                cache, cache_index, encoder_out, dist, shd, remat: bool,
                remat_policy: str = "nothing", unroll: bool = False,
                lengths=None, block_tables=None, reduce=None):
-    def body(carry, xs):
-        x, aux = carry
-        bp, cache_sb = xs
+    def step(x, aux, bp, cache_sb, layer):
         new_cache_sb = {}
         for i, kind in enumerate(pattern):
             sl = f"slot{i}"
@@ -272,9 +278,27 @@ def _run_stack(blocks, x, cfg: ModelConfig, pattern, *, positions, causal,
                     cache=None if cache_sb is None else cache_sb[sl],
                     cache_index=cache_index, encoder_out=encoder_out,
                     dist=dist, shd=shd, aux=aux, lengths=lengths,
-                    block_tables=block_tables, reduce=reduce)
+                    block_tables=block_tables, reduce=reduce, layer=layer)
             new_cache_sb[sl] = nc if nc is not None else {}
-        return (shd("resid", x), aux), new_cache_sb
+        return shd("resid", x), aux, new_cache_sb
+
+    # A paged pool rides whole in the carry and each layer reads and
+    # writes its own rows in place (xs: the layer index).  Scanning it as
+    # xs/ys would slice and restack one layer of every leaf per step, and
+    # on the TPU each slice also changes layout: four pool-layer copies
+    # per leaf per layer.  Other caches are per-layer xs/ys.
+    pooled = cache is not None and any(
+        "k_pages" in c.get("self", {}) for c in cache.values())
+
+    def body(carry, xs):
+        if pooled:
+            x, aux, pool = carry
+            bp, layer = xs
+            return step(x, aux, bp, pool, layer), None
+        x, aux = carry
+        bp, cache_sb = xs
+        x, aux, new_cache_sb = step(x, aux, bp, cache_sb, None)
+        return (x, aux), new_cache_sb
 
     if remat:
         pol = REMAT_POLICIES.get(remat_policy, None)
@@ -286,23 +310,25 @@ def _run_stack(blocks, x, cfg: ModelConfig, pattern, *, positions, causal,
         else:
             kw = {}
         body = jax.checkpoint(body, **kw)
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    carry = (x, jnp.zeros((), jnp.float32)) + ((cache,) if pooled else ())
+    xs = (blocks, np.arange(n, dtype=np.int32) if pooled else cache)
     if unroll:
         # python loop: per-layer kernel streams stay visible to the SKIP
-        # profiler (and to XLA's scheduler for overlap experiments)
-        n = jax.tree.leaves(blocks)[0].shape[0]
-        carry = (x, jnp.zeros((), jnp.float32))
-        caches = []
+        # profiler (and to XLA's scheduler for overlap experiments); a
+        # pool's layer index is a static int here
+        ys = []
         for i in range(n):
             with jax.named_scope(f"layer{i}"):
-                xs = jax.tree.map(lambda a: a[i], (blocks, cache))
-                carry, nc = body(carry, xs)
-            caches.append(nc)
-        new_cache = jax.tree.map(lambda *cs: jnp.stack(cs), *caches) \
-            if caches and jax.tree.leaves(caches[0]) else caches[0]
-        (x, aux) = carry
-        return x, aux, new_cache
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (blocks, cache))
+                carry, y = body(carry, jax.tree.map(lambda a: a[i], xs))
+            ys.append(y)
+        new_cache = jax.tree.map(lambda *cs: jnp.stack(cs), *ys) \
+            if jax.tree.leaves(ys[0]) else ys[0]
+    else:
+        carry, new_cache = jax.lax.scan(body, carry, xs)
+    if pooled:
+        return carry
+    x, aux = carry
     return x, aux, new_cache
 
 

@@ -1,7 +1,14 @@
 """Paged KV-cache subsystem: allocator invariants, paged decode-attention
-kernel vs oracles, paged forward vs contiguous forward, engine-level token
-equivalence (plain / chunked prefill / preempt-recompute / preempt-offload),
-the admit() overflow guard, and LC-vs-CC offload pricing."""
+kernel vs oracles, paged forward vs contiguous forward (and the pool's
+page rows against the contiguous cache's entries), engine-level token
+equivalence (plain / chunked prefill / preempt-recompute / preempt-offload,
+tp=2 against tp=1), the page ops on the pool's rows, the admit() overflow
+guard, and LC-vs-CC offload pricing."""
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,10 +17,12 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.core.device_model import PLATFORMS, offload_cost_s
 from repro.inference.engine import Request, ServeEngine
+from repro.inference.kv_quant import dequantize_kv
 from repro.kernels.decode_attention.ops import paged_decode_attention
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)
-from repro.kvcache import BlockPool, HostOffloadTier, default_num_blocks
+from repro.kvcache import (BlockPool, HostOffloadTier, PagedKVCache,
+                           default_num_blocks)
 from repro.models import forward, init_params, make_cache, make_paged_cache
 from repro.telemetry.characterize import memory_pressure_sweep
 
@@ -201,6 +210,219 @@ def test_forward_paged_matches_contiguous(small_model):
                          block_tables=jnp.asarray(tables))
     np.testing.assert_allclose(np.asarray(lg_c), np.asarray(lg_p),
                                atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------------------ pool rows
+ROW_BS, ROW_MAX_LEN = 4, 16                       # 4 blocks per table row
+ROW_TABLES = np.array([[5, 2, 9, 0], [7, 11, 3, 8]], np.int32)
+ROW_PAGES = 12                                    # 1, 4, 6, 10: no row's
+ROW_PROMPTS = [[5, 9, 2, 7, 1, 4, 8, 3, 6, 2, 7, 7, 1, 9, 4, 2],
+               [3, 8, 4, 4, 6, 2, 9, 1, 5, 6, 3, 2, 8, 8, 5, 1]]
+
+
+def _canonical(leaf, hkv, bs=ROW_BS):
+    """A pool leaf (L, G, P, bs·(hkv/G)·w), read by its documented layout
+    (one row per layer, head group and page, token-major) as
+    (L, P, bs, hkv, w)."""
+    leaf = np.asarray(leaf)
+    n_l, n_g, n_p, row = leaf.shape
+    w = row * n_g // (bs * hkv)
+    leaf = leaf.reshape(n_l, n_g, n_p, bs, hkv // n_g, w)
+    return leaf.transpose(0, 2, 3, 1, 4, 5).reshape(n_l, n_p, bs, hkv, w)
+
+
+def _row_view(leaf, table, hkv):
+    """(L, NB·bs, hkv, w): the tokens table row ``table`` maps."""
+    c = _canonical(leaf, hkv)[:, table]
+    return c.reshape(c.shape[0], -1, hkv, c.shape[-1])
+
+
+def _contig_chunk(params, cfg, cache, row, toks, t0):
+    sub = jax.tree.map(lambda c: c[:, row:row + 1], cache)
+    lg, _, sub = forward(params, jnp.asarray([toks]), cfg, cache=sub,
+                         cache_index=jnp.asarray(t0, jnp.int32))
+    return lg[0], jax.tree.map(lambda c, s_: c.at[:, row:row + 1].set(s_),
+                               cache, sub)
+
+
+def _paged_chunk(params, cfg, pool, row, toks, t0):
+    lg, _, pool = forward(params, jnp.asarray([toks]), cfg, cache=pool,
+                          cache_index=jnp.asarray(t0, jnp.int32),
+                          block_tables=jnp.asarray(ROW_TABLES[row:row + 1]))
+    return lg[0], pool
+
+
+# case: (tokens each row holds before the call, tokens the call writes)
+ROW_CASES = {
+    "decode": ((6, 9), 1),
+    "aligned_chunk": ((4, 9), 8),          # row 0: blocks 1 and 2, whole
+    "midpage_chunk": ((6, 9), 7),          # row 0: tokens 6..12, 3 blocks
+    "verify_past_page_and_max_len": ((6, 14), 4),   # 6..9; 14..17 > 16
+    "sentinel_row": ((6, 9), 1),           # row 1's table all sentinel
+    "int8": ((6, 9), 1),
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_CASES))
+def test_paged_rows_match_contiguous(small_model, case):
+    """Each kind of paged write, as whole page rows, against the
+    contiguous cache: the same logits, the same K/V at every position the
+    rows map, and every other page as it was."""
+    cfg, params = small_model
+    held, width = ROW_CASES[case]
+    hkv = cfg.n_kv_heads
+    kv_dtype = "int8" if case == "int8" else "bf16"
+    ccache = make_cache(cfg, 2, ROW_MAX_LEN, src_len=1, dtype=cfg.cdtype)
+    pool = make_paged_cache(cfg, ROW_PAGES, ROW_BS, dtype=cfg.cdtype,
+                            kv_dtype=kv_dtype)
+    for row, n in enumerate(held):
+        _, ccache = _contig_chunk(params, cfg, ccache, row,
+                                  ROW_PROMPTS[row][:n], 0)
+        _, pool = _paged_chunk(params, cfg, pool, row,
+                               ROW_PROMPTS[row][:n], 0)
+    before = pool
+    tables = ROW_TABLES.copy()
+    rows = [0, 1]
+    if case.endswith("chunk"):
+        toks = ROW_PROMPTS[0][held[0]:held[0] + width]
+        lc, ccache = _contig_chunk(params, cfg, ccache, 0, toks, held[0])
+        lp, pool = _paged_chunk(params, cfg, pool, 0, toks, held[0])
+        rows = [0]
+    else:
+        toks = jnp.asarray([[7 + r + j for j in range(width)]
+                            for r in range(2)], jnp.int32)
+        lengths = jnp.asarray(held, jnp.int32)
+        if case == "sentinel_row":
+            tables[1] = [ROW_PAGES, ROW_PAGES + 1000, ROW_PAGES, ROW_PAGES]
+            rows = [0]
+        lp, _, pool = forward(params, toks, cfg, cache=pool,
+                              lengths=lengths,
+                              block_tables=jnp.asarray(tables))
+        sub = jax.tree.map(lambda c: c[:, rows[0]:rows[-1] + 1], ccache)
+        lc, _, sub = forward(params, toks[rows[0]:rows[-1] + 1], cfg,
+                             cache=sub, lengths=lengths[rows[0]:rows[-1] + 1])
+        ccache = jax.tree.map(
+            lambda c, s_: c.at[:, rows[0]:rows[-1] + 1].set(s_), ccache, sub)
+        lp = lp[rows[0]:rows[-1] + 1]
+    tol = 5e-2 if kv_dtype == "int8" else 1e-5
+    np.testing.assert_allclose(np.asarray(lc), np.asarray(lp), atol=tol,
+                               rtol=tol)
+
+    for sl, c in ccache.items():
+        p = pool[sl]["self"]
+        for name in ("k", "v"):
+            want = np.asarray(c["self"][name])          # (L, B, T, hkv, hd)
+            for r in rows:
+                n = min(held[r] + width, ROW_MAX_LEN)
+                got = _row_view(p[f"{name}_pages"], ROW_TABLES[r], hkv)[:, :n]
+                if kv_dtype == "int8":
+                    scale = _row_view(p[f"{name}_scale"], ROW_TABLES[r],
+                                      hkv)[:, :n, :, 0]
+                    got = np.asarray(dequantize_kv(got, scale, jnp.float32))
+                    # round trip: at most half a step of each entry's
+                    # scale.  Layer 0's entries depend on the tokens alone;
+                    # a later layer's on the quantized attention below it
+                    assert (np.abs(got[0] - want[0, r, :n])
+                            <= scale[0, ..., None] / 2 + 1e-6).all()
+                else:
+                    np.testing.assert_allclose(got, want[:, r, :n],
+                                               atol=1e-6, rtol=1e-6)
+        written = {int(pg) for r in rows for pg in ROW_TABLES[r]}
+        for name, leaf in p.items():
+            old = _canonical(before[sl]["self"][name], hkv)
+            new = _canonical(leaf, hkv)
+            for pg in set(range(ROW_PAGES)) - written:
+                np.testing.assert_array_equal(new[:, pg], old[:, pg])
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_page_ops_move_whole_rows(small_model, kv_dtype):
+    """zero_pages, copy_pages and the offload round trip act on one page
+    of every leaf (all layers, all head groups) and on nothing else."""
+    cfg, _ = small_model
+    kv = PagedKVCache(cfg, num_blocks=6, block_size=ROW_BS,
+                      max_len=ROW_MAX_LEN, kv_dtype=kv_dtype)
+    keys = iter(jax.random.split(KEY, 8))
+    pages = jax.tree.map(
+        lambda p: (jax.random.normal(next(keys), p.shape) * 50
+                   ).astype(p.dtype), kv.make_pages(groups=2))
+    leaves = [np.asarray(x) for x in jax.tree.leaves(pages)]
+    assert kv.block_bytes(pages, kv.num_blocks) == \
+        sum(x.nbytes for x in leaves)
+
+    def check(out, expect):
+        for got, orig in zip(jax.tree.leaves(out), leaves):
+            got = np.asarray(got)
+            for pg in range(kv.num_blocks):
+                src = expect.get(pg, pg)
+                want = (np.zeros_like(orig[:, :, pg]) if src is None
+                        else orig[:, :, src])
+                np.testing.assert_array_equal(got[:, :, pg], want)
+
+    check(kv.copy_pages(pages, 1, 4), {4: 1})
+    zeroed = kv.zero_pages(pages, [0, 3])
+    check(zeroed, {0: None, 3: None})
+    host = kv.gather_host(pages, [2, 5])
+    check(kv.scatter_host(zeroed, [0, 3], host), {0: 2, 3: 5})
+
+
+def test_tp2_pool_holds_whole_heads_per_device():
+    """tp=2 keeps two whole-head groups of the pool, one per device, and
+    serves the same tokens as tp=1 with the same K/V in every page."""
+    code = """
+    import jax, numpy as np
+    from repro.configs import get_config, reduced
+    from repro.inference.engine import Request, ServeEngine
+    from repro.models import init_params
+
+    cfg = reduced(get_config("smollm-360m"), n_layers=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    hkv, bs = cfg.n_kv_heads, 4
+
+    def canonical(leaf):
+        leaf = np.asarray(leaf)
+        n_l, n_g, n_p, row = leaf.shape
+        leaf = leaf.reshape(n_l, n_g, n_p, bs, hkv // n_g, -1)
+        return leaf.transpose(0, 2, 3, 1, 4, 5).reshape(n_l, n_p, -1)
+
+    engines, reqs = {}, {}
+    for tp in (1, 2):
+        eng = ServeEngine(cfg, params, max_batch=2, max_len=32, tp=tp,
+                          cache="paged", block_size=bs, prefill_chunk=6)
+        rng = np.random.default_rng(0)
+        reqs[tp] = [Request(i, prompt=list(rng.integers(
+            0, cfg.vocab_size, n)), max_new_tokens=8)
+            for i, n in enumerate((11, 6))]
+        for r in reqs[tp]:
+            eng.submit(r)
+        for _ in range(5):
+            eng.tick()
+        engines[tp] = eng
+    one, two = (jax.tree.leaves(engines[t].cache) for t in (1, 2))
+    for a, b in zip(one, two):
+        assert a.shape[1] == 1 and b.shape[1] == 2
+        for shard in b.addressable_shards:
+            g = shard.index[1].start
+            assert shard.data.shape == (b.shape[0], 1) + b.shape[2:]
+            np.testing.assert_array_equal(np.asarray(shard.data),
+                                          np.asarray(b)[:, g:g + 1])
+        np.testing.assert_allclose(canonical(a), canonical(b), atol=1e-6)
+    for eng in engines.values():
+        while eng.tick():
+            pass
+    toks = {t: [r.generated for r in rs] for t, rs in reqs.items()}
+    assert toks[1] == toks[2] and all(len(g) == 8 for g in toks[1])
+    print("WHOLE_HEADS_OK")
+    """
+    repo = Path(__file__).resolve().parents[1]
+    env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+           "PYTHONPATH": str(repo / "src"), "JAX_PLATFORMS": "cpu",
+           "PATH": "/usr/bin:/bin"}
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env,
+                         cwd=str(repo), timeout=560)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "WHOLE_HEADS_OK" in out.stdout
 
 
 # ------------------------------------------------------------ engine
@@ -531,7 +753,7 @@ def test_cow_write_divergence_preserves_donor_pages(small_model):
                       block_size=4, share_prefix=True)
     pool = eng.kv.pool
     ids = pool.alloc("donor", 2)
-    eng.cache = jax.tree.map(lambda p: p.at[:, ids[0]].set(1), eng.cache)
+    eng.cache = jax.tree.map(lambda p: p.at[:, :, ids[0]].set(1), eng.cache)
     pool.adopt("writer", ids)
     assert pool.shared_blocks == 2
     # writer is about to write tokens [0, 4): block 0 must diverge
@@ -541,8 +763,9 @@ def test_cow_write_divergence_preserves_donor_pages(small_model):
     assert w[0] != ids[0] and w[1] == ids[1]
     # the copied page carries the donor's contents
     leaf = jax.tree.leaves(eng.cache)[0]
-    np.testing.assert_array_equal(np.asarray(leaf[:, w[0]]),
-                                  np.asarray(leaf[:, ids[0]]))
+    np.testing.assert_array_equal(np.asarray(leaf[:, :, w[0]]),
+                                  np.asarray(leaf[:, :, ids[0]]))
+    assert np.asarray(leaf[:, :, w[0]]).min() == 1
     # donor's view is untouched and still shared on block 1 only
     assert pool.owned("donor") == ids
     assert pool.ref_count(ids[0]) == 1 and pool.ref_count(ids[1]) == 2

@@ -3,19 +3,27 @@
 Each test lowers a Pallas kernel with ``interpret=False`` for one chip of a
 described (not attached) TPU v5e and compiles it with the TPU compiler, so a
 tile layout or VMEM budget the chip refuses fails here, on the CPU, instead
-of on the chip.  Nothing runs: these tests say nothing about results or
-times.  The topology is described inside a fixture (never at import), and
+of on the chip.  The served step programs are compiled the same way at the
+smollm-chat benchmark cell's shapes, to check what the compiler makes of the
+paged pool in the layer loop.  Nothing runs: these tests say nothing about
+results or times.  The topology is described inside a fixture (never at import), and
 the persistent compilation cache is off for this file, since a compile for a
 described chip can be written to it but never read back.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.inference.backends.bodies import make_step_bodies
 from repro.kernels.decode_attention.ops import paged_decode_attention
 from repro.kernels.fused import residual_rmsnorm, rmsnorm_matmul
+from repro.kvcache import PagedKVCache
+from repro.models import init_params
 
 CFG = get_config("smollm-360m")
 BATCH, PAGES, BLOCK, MAX_LEN = 8, 512, 16, 2048
@@ -90,3 +98,69 @@ def test_residual_rmsnorm_compiles(one_chip):
         x, _sds((d,), jnp.bfloat16, one_chip), x,
         eps=CFG.norm_eps, interpret=False).compile()
     assert _has_kernel(compiled)
+
+
+# ------------------------------------------------------ served step programs
+# smollm-chat's serving shapes: 64 slots, 8192 pool blocks of 16 tokens,
+# 2048-token context, 512-token prefill chunks
+SLOTS, POOL_BLOCKS, CHUNK = 64, 8192, 512
+# what the compiler may keep beside arguments and output, per program
+TEMP_LIMIT = {"paged_decode": 0.4e9, "paged_prefill": 0.1e9}
+
+_INSTR = re.compile(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+
+
+def _pool_layer_moves(hlo: str, layer: tuple) -> list:
+    """Instructions that move one layer of a pool leaf: a ``copy`` whose
+    shape, size-1 axes aside, is ``layer`` (pages first, in any split of the
+    row), or a ``dynamic-update-slice`` writing such an operand.  The
+    gathered view of every row's table may hold as many elements, but it is
+    not pages-major."""
+    def is_layer(dims):
+        dims = [d for d in dims if d != 1]
+        return dims[:1] == [layer[0]] and math.prod(dims) == math.prod(layer)
+
+    shapes, found = {}, []
+    for name, shape, op, args in _INSTR.findall(hlo):
+        shapes[name] = [int(d) for d in shape.split(",") if d]
+        if op == "copy" and is_layer(shapes[name]):
+            found.append(name)
+        elif op == "dynamic-update-slice":
+            update = re.findall(r"%([\w.-]+)", args)[1]
+            if is_layer(shapes.get(update, [])):
+                found.append(name)
+    return found
+
+
+@pytest.fixture(scope="module")
+def served_steps(one_chip):
+    """The paged decode step and a full prefill chunk, compiled as
+    ``LocalBackend`` jits them, with the pool leaves' shape."""
+    kv = PagedKVCache(CFG, num_blocks=POOL_BLOCKS, block_size=BLOCK,
+                      max_len=MAX_LEN)
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0),
+                                                      CFG)))
+    pages = place(jax.eval_shape(kv.make_pages))
+    nb, i32 = kv.nb_per_slot, jnp.int32
+    args = {"paged_decode": ((SLOTS, 1), (SLOTS,), (SLOTS, nb)),
+            "paged_prefill": ((1, CHUNK), (nb,), ())}
+    bodies = make_step_bodies(CFG)
+    compiled = {
+        name: jax.jit(getattr(bodies, name)).lower(
+            params, pages, *(_sds(s, i32, one_chip) for s in shapes)
+        ).compile()
+        for name, shapes in args.items()}
+    return compiled, jax.tree.leaves(pages)[0].shape
+
+
+@pytest.mark.parametrize("program", ["paged_decode", "paged_prefill"])
+def test_step_keeps_pool_in_place(served_steps, program):
+    """The layer loop reads and writes the pool where it lies: no copy of a
+    pool layer, and temporaries far below one layer of the pool."""
+    compiled, leaf = served_steps
+    c = compiled[program]
+    layer = tuple(d for d in leaf[1:] if d != 1)       # (pages, row)
+    assert _pool_layer_moves(c.as_text(), layer) == []
+    assert c.memory_analysis().temp_size_in_bytes <= TEMP_LIMIT[program]

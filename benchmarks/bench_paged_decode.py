@@ -17,8 +17,9 @@ from repro.core.device_model import PLATFORMS, offload_cost_s
 from repro.inference.engine import Request, ServeEngine
 from repro.inference.kv_quant import quantize_kv
 from repro.kernels import pallas_interpret
-from repro.kernels.decode_attention.ops import (decode_attention,
-                                                paged_decode_attention)
+from repro.kernels.decode_attention.ops import (
+    decode_attention, paged_decode_attention, paged_decode_attention_quant)
+from repro.kernels.decode_attention.ref import pool_rows
 from repro.models import init_params
 
 ARCH = "smollm-360m"
@@ -66,8 +67,12 @@ def run() -> list[str]:
     interp = pallas_interpret()
     tc = _time(lambda: decode_attention(q, k, v, t, scale=0.2, block_kv=bs,
                                         interpret=interp))
-    tp = _time(lambda: paged_decode_attention(q, kp, vp, tables, lens,
-                                              scale=0.2, interpret=interp))
+    # the served pool's rows: one bf16 layer, read in place by the kernel
+    qb = q.astype(jnp.bfloat16)
+    kr, vr = (pool_rows(x.astype(jnp.bfloat16)) for x in (kp, vp))
+    tp = _time(lambda: paged_decode_attention(
+        qb, kr, vr, 0, tables, lens - 1, lens - 1, scale=0.2, kv_heads=HKV,
+        interpret=interp))
     rows.append(csv_row("paged_decode/ops_contiguous", tc * 1e6,
                         f"B={B};T={t};block_kv={bs}"))
     rows.append(csv_row("paged_decode/ops_paged", tp * 1e6,
@@ -77,9 +82,8 @@ def run() -> list[str]:
     # dequantized inside the kernel after each page DMA
     qk, sk = quantize_kv(kp)
     qv, sv = quantize_kv(vp)
-    tq = _time(lambda: paged_decode_attention(q, qk, qv, tables, lens,
-                                              scale=0.2, k_scale=sk,
-                                              v_scale=sv, interpret=interp))
+    tq = _time(lambda: paged_decode_attention_quant(
+        q, qk, qv, sk, sv, tables, lens, scale=0.2, interpret=interp))
     rows.append(csv_row("paged_decode/ops_paged_int8", tq * 1e6,
                         f"B={B};pages={B * nb};bs={bs};"
                         f"vs_paged_bf16={tq / tp:.2f}x"))

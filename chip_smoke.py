@@ -174,14 +174,16 @@ def init_on_device(cfg, device):
 
 # --------------------------------------------------------------- one chip
 def kernel_phase(cfg, interpret: bool = False) -> dict:
-    """Phase 6: the bf16 and int8 paged decode kernels at the model's
-    widths against ``kernels/decode_attention/ref.py``."""
+    """Phase 6: the bf16 pool-row and int8 paged decode kernels at the
+    model's widths against ``kernels/decode_attention/ref.py``."""
     import jax
     import jax.numpy as jnp
     from repro.inference.kv_quant import quantize_kv
-    from repro.kernels.decode_attention.ops import paged_decode_attention
+    from repro.kernels.decode_attention.ops import (
+        paged_decode_attention, paged_decode_attention_quant)
     from repro.kernels.decode_attention.ref import (
-        paged_decode_attention_quant_ref, paged_decode_attention_ref)
+        paged_decode_attention_quant_ref, paged_decode_attention_ref,
+        pool_rows)
     b, pages, bs = MAX_BATCH, 512, PAGED["block_size"]
     nb = MAX_LEN // bs
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -194,14 +196,15 @@ def kernel_phase(cfg, interpret: bool = False) -> dict:
     lens = jnp.asarray(rng.integers(1, nb * bs + 1, b), jnp.int32)
     scale = hd ** -0.5
     out = {}
-    o = paged_decode_attention(q, kp, vp, tables, lens, scale=scale,
-                               interpret=interpret)
+    o = paged_decode_attention(q, pool_rows(kp), pool_rows(vp), 0, tables,
+                               lens - 1, lens - 1, scale=scale,
+                               kv_heads=hkv, interpret=interpret)
     r = paged_decode_attention_ref(q, kp, vp, tables, lens, scale=scale)
     out["bf16"] = (o, r)
     qk, sk = quantize_kv(kp)
     qv, sv = quantize_kv(vp)
-    o = paged_decode_attention(q, qk, qv, tables, lens, scale=scale,
-                               k_scale=sk, v_scale=sv, interpret=interpret)
+    o = paged_decode_attention_quant(q, qk, qv, sk, sv, tables, lens,
+                                     scale=scale, interpret=interpret)
     r = paged_decode_attention_quant_ref(q, qk, qv, sk, sv, tables, lens,
                                          scale=scale)
     out["int8"] = (o, r)

@@ -206,8 +206,8 @@ def paged_cache_specs(pages, cfg: ModelConfig, mesh: Mesh,
 
     Page pools are global (shared across batch rows through block tables),
     so there is no batch axis to put ``data`` on.  Every leaf —
-    ``k_pages``/``v_pages`` (n_sb, G, P, bs·(HKV/G)·hd) and, when the pool
-    is quantized, ``k_scale``/``v_scale`` (n_sb, G, P, bs·(HKV/G)) — shards
+    ``k_pages``/``v_pages`` (n_sb, G, P, *row) and, when the pool is
+    quantized, ``k_scale``/``v_scale`` (n_sb, G, P, bs·(HKV/G)) — shards
     its head-group axis G over ``tp``: built with G = tp, each device holds
     whole heads, and inside ``shard_map`` its block is a valid pool of the
     per-device config.  A G that ``tp`` does not divide falls back to
@@ -215,7 +215,8 @@ def paged_cache_specs(pages, cfg: ModelConfig, mesh: Mesh,
     def spec_for(path, leaf):
         name = getattr(path[-1], "key", getattr(path[-1], "name", ""))
         if name in ("k_pages", "v_pages", "k_scale", "v_scale"):
-            return valid_spec(leaf.shape, P(None, tp, None, None), mesh)
+            return valid_spec(leaf.shape,
+                              P(None, tp, *(None,) * (leaf.ndim - 2)), mesh)
         return valid_spec(leaf.shape, P(*(None,) * len(leaf.shape)), mesh)
 
     flat = jax.tree_util.tree_flatten_with_path(pages)[0]

@@ -1095,8 +1095,13 @@ class ServeEngine:
                 bt = jnp.asarray(self.kv.block_tables(owners))
                 toks, lengths = jnp.asarray(toks), jnp.asarray(self.lengths)
             if profiling():
-                span.set_metadata(rows=len(active), live_tokens=int(
-                    self.lengths[active].sum()) + len(active))
+                held = self.lengths[active] + 1
+                nb = self.kv.nb_per_slot
+                span.set_metadata(
+                    rows=len(active), live_tokens=int(held.sum()),
+                    kv_pages=int(np.minimum(-(-held // self.kv.block_size),
+                                            nb).sum()),
+                    table_pages=self.B * nb)
             t0 = time.perf_counter()
             logits, self.cache = self.backend.paged_decode(
                 self.cache, toks, lengths, bt)

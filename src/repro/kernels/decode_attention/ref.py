@@ -2,14 +2,17 @@
 
 ``decode_attention_ref`` reads a contiguous per-sequence cache;
 ``paged_decode_attention_ref`` reads the same logical KV through a
-block table over a pool of fixed-size token pages (the paged KV cache
-layout of ``repro.kvcache``): position ``t`` of row ``b`` lives at
-``pages[tables[b, t // bs], t % bs]``.
+block table over a pool of fixed-size token pages: position ``t`` of row
+``b`` lives at ``pages[tables[b, t // bs], t % bs]``.  ``pool_rows``
+writes such pages as one layer of the served pool's rows (``layout.py``),
+the layout ``ops.paged_decode_attention`` reads.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.decode_attention.layout import row_shape
 
 NEG_INF = -2.3819763e38
 
@@ -63,3 +66,14 @@ def paged_decode_attention_quant_ref(q, k_pages, v_pages, k_scale, v_scale,
     vf = v_pages.astype(jnp.float32) * v_scale[..., None]
     return paged_decode_attention_ref(q, kf, vf, block_tables, kv_lens,
                                       scale=scale)
+
+
+def pool_rows(pages, groups: int = 1):
+    """(P, bs, HKV, w) token pages as one layer of pool rows,
+    (1, groups, P, *row_shape): per head group and page, the group's heads
+    head-major (head, token, value)."""
+    n_pages, bs, hkv, w = pages.shape
+    heads = hkv // groups
+    rows = pages.reshape(n_pages, bs, groups, heads, w)
+    rows = rows.transpose(2, 0, 3, 1, 4)                 # (G, P, heads, bs, w)
+    return rows.reshape((1, groups, n_pages) + row_shape(bs, heads, w))

@@ -3,8 +3,8 @@
 ``PagedKVCache`` owns the geometry (block size, pool size, blocks per
 slot) and the ``BlockPool`` allocator; the device pages themselves are a
 plain cache pytree (``models.make_paged_cache`` — leaves shaped
-``(n_superblocks, G, P, row)``: one lane-dense row per layer, head group
-and page) that the engine threads through ``forward`` functionally.  The
+``(n_superblocks, G, P, *row)``: one row per layer, head group and
+page) that the engine threads through ``forward`` functionally.  The
 page axis is axis 2 of every leaf.  Methods that touch pages take and return the
 pytree rather than mutating hidden state, so jit boundaries stay clean.
 """
@@ -23,7 +23,7 @@ from repro.models import make_paged_cache
 
 def _rows(leaf, idx):
     """Indices of pages ``idx``' rows in every layer and head group of a
-    pool leaf: ``leaf[_rows(leaf, idx)]`` is (L, G, len(idx), row).
+    pool leaf: ``leaf[_rows(leaf, idx)]`` is (L, G, len(idx), *row).
     Indexing all three leading axes leaves the row alone as the window, so
     on the TPU a scatter writes the rows in place; a page index on axis 2
     alone makes XLA relayout the whole pool around the scatter."""
@@ -42,13 +42,13 @@ def _zero_rows(leaf, idx):
 
 @jax.jit
 def _take_rows(leaf, idx):
-    """Pages ``idx``' rows of ``leaf``: (L, G, len(idx), row)."""
+    """Pages ``idx``' rows of ``leaf``: (L, G, len(idx), *row)."""
     return leaf[_rows(leaf, idx)]
 
 
 @jax.jit
 def _put_rows(leaf, idx, rows):
-    """``leaf`` with pages ``idx`` set to ``rows`` (L, G, len(idx), row)."""
+    """``leaf`` with pages ``idx`` set to ``rows`` (L, G, len(idx), *row)."""
     return leaf.at[_rows(leaf, idx)].set(rows.astype(leaf.dtype))
 
 

@@ -1,11 +1,14 @@
 """Attention: GQA, sliding-window, logit softcap, cross-attention, KV cache.
 
-This is the pure-XLA reference path used for distribution lowering and smoke
-tests; the Pallas flash/decode kernels in ``repro/kernels`` implement the
-same math as the TPU-target hot-spot (see kernels/*/ref.py).
+This is the pure-XLA path used for distribution lowering and smoke tests;
+the Pallas flash/decode kernels in ``repro/kernels`` implement the same math
+as the TPU-target hot-spot (see kernels/*/ref.py).  One of them is on the
+served path: on the TPU a paged decode step reads the pool through
+``paged_decode_attention`` instead of the XLA gather (``_decode_kernel``).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -13,6 +16,10 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.inference.kv_quant import dequantize_kv, quantize_kv
+from repro.kernels import pallas_interpret
+from repro.kernels.decode_attention.layout import row_shape
+from repro.kernels.decode_attention.ops import (decode_kernel_fits,
+                                                paged_decode_attention)
 from repro.layers.common import apply_rope, dense_init, softcap, split_keys
 
 NEG_INF = -2.3819763e38  # large negative, bf16-safe
@@ -174,25 +181,27 @@ def _touched_pages(block_tables, positions, bs: int, n_pages: int):
 def _write_rows(pool, layer, pages, src, new):
     """Whole-row read-modify-write of layer ``layer`` of one pool leaf.
 
-    pool: (L, G, P, bs·(H/G)·w) — per layer, head group and page one row,
-    token-major; new: (B, S, H, w) values of the S new tokens (w = hd for
-    K/V payloads, 1 for int8 scales).  The touched rows are gathered, the
-    new tokens merged in with a select, and the rows scattered back whole;
-    out-of-range pages (``_touched_pages``' sentinel) drop.  Per-element
-    scatters would make XLA relayout the whole pool on the TPU.
+    pool: (L, G, P, *row) — per layer, head group and page one row of
+    bs·(H/G)·w values, head-major (head, token, value) whatever its
+    trailing shape (``row_shape``); new: (B, S, H, w) values of the S new
+    tokens (w = hd for K/V payloads, 1 for int8 scales).  The touched rows
+    are gathered, the new tokens merged in with a select, and the rows
+    scattered back whole; out-of-range pages (``_touched_pages``'
+    sentinel) drop.  Per-element scatters would make XLA relayout the
+    whole pool on the TPU.
     """
     n_groups, n_pages = pool.shape[1], pool.shape[2]
     b, _, h, w = new.shape
     n_touch, bs = src.shape[1], src.shape[2]
     g = jnp.arange(n_groups)[:, None, None]
     old = pool[layer, g, jnp.minimum(pages, n_pages - 1)[None]]
-    old = old.reshape(n_groups, b, n_touch, bs, -1)
+    old = old.reshape(n_groups, b, n_touch, h // n_groups, bs, w)
     tok = new[jnp.arange(b)[:, None], jnp.maximum(src, 0).reshape(b, -1)]
-    tok = tok.reshape(b, n_touch, bs, n_groups, (h // n_groups) * w)
-    tok = tok.transpose(3, 0, 1, 2, 4).astype(pool.dtype)
-    rows = jnp.where((src >= 0)[None, ..., None], tok, old)
+    tok = tok.reshape(b, n_touch, bs, n_groups, h // n_groups, w)
+    tok = tok.transpose(3, 0, 1, 4, 2, 5).astype(pool.dtype)
+    rows = jnp.where((src >= 0)[None, :, :, None, :, None], tok, old)
     return pool.at[layer, g, pages[None]].set(
-        rows.reshape(n_groups, b, n_touch, -1), mode="drop")
+        rows.reshape((n_groups, b, n_touch) + pool.shape[3:]), mode="drop")
 
 
 def _read_rows(pool, layer, safe, h, w):
@@ -200,9 +209,19 @@ def _read_rows(pool, layer, safe, h, w):
     n_groups = pool.shape[1]
     b, nb = safe.shape
     g = jnp.arange(n_groups)[:, None, None]
-    rows = pool[layer, g, safe[None]]                   # (G, B, NB, row)
-    rows = rows.reshape(n_groups, b, nb, -1, h // n_groups, w)
-    return rows.transpose(1, 2, 3, 0, 4, 5).reshape(b, -1, h, w)
+    rows = pool[layer, g, safe[None]]                   # (G, B, NB, *row)
+    rows = rows.reshape(n_groups, b, nb, h // n_groups, -1, w)
+    return rows.transpose(1, 2, 4, 0, 3, 5).reshape(b, -1, h, w)
+
+
+def _decode_kernel(pool, tables, n_kv, hd):
+    """The Pallas read for a decode call over ``pool``, or None where the
+    XLA read stays: off the TPU (there the XLA read is the reference, and
+    tests patch this to run the kernel in the interpreter), and for a pool
+    the kernel cannot read (``decode_kernel_fits``)."""
+    if pallas_interpret() or not decode_kernel_fits(pool, tables, n_kv, hd):
+        return None
+    return paged_decode_attention
 
 
 def _paged_attention_fwd(q, k, v, cache, layer, block_tables, positions,
@@ -211,20 +230,22 @@ def _paged_attention_fwd(q, k, v, cache, layer, block_tables, positions,
     """Self-attention of layer ``layer`` over the block-table paged pool.
 
     ``cache`` holds the whole stack's pool (``make_paged_self_cache``
-    stacked over layers): k_pages/v_pages (L, G, P, bs·(HKV/G)·hd), one
-    lane-dense row per (layer, head group, page), token-major within the
-    row.  ``block_tables`` (B, NB) maps a row's logical token position ``t``
-    to page ``bt[b, t // bs]``.  The new K/V entries are written as whole
-    page rows (``_write_rows``), then each row's logical view is gathered
-    back for the masked attention core: the pure-XLA analogue of
-    ``repro.kernels.decode_attention.paged_decode_attention``.  Returns the
-    attention output and the pool with layer ``layer`` updated.
+    stacked over layers): k_pages/v_pages (L, G, P, *row), one row of
+    bs·(HKV/G)·hd values per (layer, head group, page), head-major within
+    the row.  ``block_tables`` (B, NB) maps a row's logical token position
+    ``t`` to page ``bt[b, t // bs]``.  The new K/V entries are written as
+    whole page rows (``_write_rows``).  A decode call (one token per row)
+    on a pool the kernel reads then runs the Pallas kernel
+    ``paged_decode_attention`` over each row's live pages where they lie
+    (``_decode_kernel``); every other call gathers each row's logical view
+    back for the masked attention core.  Returns the attention output and
+    the pool with layer ``layer`` updated.
     """
     assert block_tables is not None, "paged KV cache needs block_tables"
     b, s, hkv, hd = k.shape
     kp = cache["k_pages"]
     n_pages = kp.shape[2]
-    bs_blk = kp.shape[3] * kp.shape[1] // (hkv * hd)
+    bs_blk = math.prod(kp.shape[3:]) * kp.shape[1] // (hkv * hd)
     pages, src = _touched_pages(block_tables, positions, bs_blk, n_pages)
     if "k_scale" in cache:
         # quantize-on-write: only the int8 payload + per-(token,head) f32
@@ -236,6 +257,15 @@ def _paged_attention_fwd(q, k, v, cache, layer, block_tables, positions,
         new = {"k_pages": k, "v_pages": v}
     new_cache = {name: _write_rows(cache[name], layer, pages, src, x)
                  for name, x in new.items()}
+    kernel = None
+    if s == 1 and causal and lengths is not None:
+        kernel = _decode_kernel(kp, block_tables, hkv, hd)
+    if kernel is not None:
+        out = kernel(q[:, 0], new_cache["k_pages"], new_cache["v_pages"],
+                     layer, block_tables, lengths, positions[:, 0],
+                     scale=scale, kv_heads=hkv, window=window,
+                     cap=cfg.attn_softcap)
+        return out[:, None], new_cache
     safe = jnp.clip(block_tables, 0, n_pages - 1)
     kg, vg = (_read_rows(new_cache[n], layer, safe, hkv, hd)
               for n in ("k_pages", "v_pages"))
@@ -397,27 +427,33 @@ def make_paged_self_cache(cfg: ModelConfig, num_pages: int, block_size: int,
     """Pool-global paged KV: pages are shared by all slots via block tables
     (``repro.kvcache``) rather than pre-carved per batch row.
 
-    Each page is one lane-dense row per head group: leaves are
-    (groups, P, bs·(HKV/groups)·hd), token-major within the row, so the
-    TPU keeps the default layout with no padding.  ``groups`` > 1 splits
-    the KV heads into that many whole-head groups, the axis tensor
-    parallelism shards (``distributed.sharding.paged_cache_specs``).
+    Each page is one row per head group: leaves are (groups, P, *row),
+    bs·(HKV/groups)·hd values head-major (head, token, value), with no
+    padding on the TPU.  K/V rows take ``row_shape``: where a head's tokens
+    fill whole tiles the row is (rows, 128), so a page is one contiguous
+    run of tiles that the decode kernel copies as it lies; otherwise it
+    stays one flat lane-dense row.  ``groups`` > 1 splits the KV heads
+    into that many whole-head groups, the axis tensor parallelism shards
+    (``distributed.sharding.paged_cache_specs``).
 
-    ``quantized``: int8 payload rows plus per-(token, head) f32 scale rows
-    (G, P, bs·(HKV/groups)) (``inference.kv_quant`` numerics) — hd bytes
-    + 4 scale bytes per (token, head) instead of 2*hd, so the same pool
-    bytes hold ~2*hd/(hd+4) more tokens.
+    ``quantized``: flat int8 payload rows plus per-(token, head) f32 scale
+    rows (G, P, bs·(HKV/groups)) (``inference.kv_quant`` numerics) — hd
+    bytes + 4 scale bytes per (token, head) instead of 2*hd, so the same
+    pool bytes hold ~2*hd/(hd+4) more tokens.
     """
     if cfg.n_kv_heads % groups:
         raise ValueError(f"groups={groups} must divide "
                          f"n_kv_heads={cfg.n_kv_heads}")
-    row = block_size * (cfg.n_kv_heads // groups)
-    shape = (groups, num_pages, row * cfg.hd)
+    heads = cfg.n_kv_heads // groups
+    pages = (groups, num_pages)
     if quantized:
-        return {"k_pages": jnp.zeros(shape, jnp.int8),
-                "v_pages": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1] + (row,), jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1] + (row,), jnp.float32)}
+        flat = pages + (block_size * heads * cfg.hd,)
+        scale = pages + (block_size * heads,)
+        return {"k_pages": jnp.zeros(flat, jnp.int8),
+                "v_pages": jnp.zeros(flat, jnp.int8),
+                "k_scale": jnp.zeros(scale, jnp.float32),
+                "v_scale": jnp.zeros(scale, jnp.float32)}
+    shape = pages + row_shape(block_size, heads, cfg.hd)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 

@@ -15,9 +15,10 @@ from repro.inference.kv_quant import (KV_DTYPES, capacity_ratio,
                                       dequantize_kv, kv_entry_bytes,
                                       make_quantized_cache, quantize_kv,
                                       read_kv, write_kv)
-from repro.kernels.decode_attention.ops import paged_decode_attention
+from repro.kernels.decode_attention.ops import (paged_decode_attention,
+                                                paged_decode_attention_quant)
 from repro.kernels.decode_attention.ref import (
-    paged_decode_attention_quant_ref, paged_decode_attention_ref)
+    paged_decode_attention_quant_ref, paged_decode_attention_ref, pool_rows)
 from repro.kvcache import default_num_blocks
 from repro.models import forward, init_params, make_paged_cache
 from repro.telemetry.characterize import memory_pressure_sweep
@@ -150,8 +151,8 @@ def test_quant_paged_kernel_matches_quant_ref(shape, bs):
     b, hq, hkv, t, hd = shape
     q, _, _, qk, sk, qv, sv, tables, lens = _quant_pool(b, hq, hkv, t, hd,
                                                         bs)
-    o = paged_decode_attention(q, qk, qv, tables, lens, scale=0.2,
-                               k_scale=sk, v_scale=sv, interpret=True)
+    o = paged_decode_attention_quant(q, qk, qv, sk, sv, tables, lens,
+                                     scale=0.2, interpret=True)
     r = paged_decode_attention_quant_ref(q, qk, qv, sk, sv, tables, lens,
                                          scale=0.2)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
@@ -159,22 +160,26 @@ def test_quant_paged_kernel_matches_quant_ref(shape, bs):
 
 
 def test_quant_paged_kernel_tolerance_vs_fp_oracle():
-    b, hq, hkv, t, hd, bs = 2, 6, 2, 32, 32, 8
+    b, hq, hkv, t, hd, bs = 2, 6, 2, 64, 64, 16
     q, kp, vp, qk, sk, qv, sv, tables, lens = _quant_pool(b, hq, hkv, t,
                                                           hd, bs)
-    o = paged_decode_attention(q, qk, qv, tables, lens, scale=0.2,
-                               k_scale=sk, v_scale=sv, interpret=True)
+    o = paged_decode_attention_quant(q, qk, qv, sk, sv, tables, lens,
+                                     scale=0.2, interpret=True)
     fp = paged_decode_attention_ref(q, kp, vp, tables, lens, scale=0.2)
     # stated decode tolerance of the int8 path vs the exact fp pool: the
     # softmax mix of <=scale/2 per-element dequant error stays well under
     # 5e-2 for unit-normal KV
     err = np.abs(np.asarray(o) - np.asarray(fp)).max()
     assert err < 5e-2, err
-    # and the unquantized call on the SAME wrapper is unaffected
-    o_fp = paged_decode_attention(q, kp, vp, tables, lens, scale=0.2,
-                                  interpret=True)
-    np.testing.assert_allclose(np.asarray(o_fp), np.asarray(fp),
-                               atol=2e-5, rtol=2e-5)
+    # and the bf16 pool-row kernel over the SAME pages, unquantized, is
+    # within bf16 rounding of the fp oracle
+    last = lens - 1
+    o_fp = paged_decode_attention(
+        q.astype(jnp.bfloat16), pool_rows(kp.astype(jnp.bfloat16)),
+        pool_rows(vp.astype(jnp.bfloat16)), 0, tables, last, last,
+        scale=0.2, kv_heads=hkv, interpret=True)
+    np.testing.assert_allclose(np.asarray(o_fp, np.float32), np.asarray(fp),
+                               atol=2e-2, rtol=2e-2)
 
 
 # ------------------------------------------------------------ forward

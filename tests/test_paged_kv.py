@@ -20,7 +20,8 @@ from repro.inference.engine import Request, ServeEngine
 from repro.inference.kv_quant import dequantize_kv
 from repro.kernels.decode_attention.ops import paged_decode_attention
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
-                                                paged_decode_attention_ref)
+                                                paged_decode_attention_ref,
+                                                pool_rows)
 from repro.kvcache import (BlockPool, HostOffloadTier, PagedKVCache,
                            default_num_blocks)
 from repro.models import forward, init_params, make_cache, make_paged_cache
@@ -109,9 +110,19 @@ def _scatter_pages(k, v, lens, bs, n_pages, seed=0):
     return jnp.asarray(kp), jnp.asarray(vp), tables
 
 
+def _pool_kernel(q, kp, vp, tables, lens):
+    """The pool-row decode kernel over (P,bs,HKV,hd) pages written as one
+    layer of pool rows, with ``lens`` valid tokens per row (the query sits
+    at position lens - 1)."""
+    last = jnp.asarray(lens) - 1
+    return paged_decode_attention(
+        q, pool_rows(kp), pool_rows(vp), 0, jnp.asarray(tables), last, last,
+        scale=0.2, kv_heads=kp.shape[2], interpret=True)
+
+
 @pytest.mark.parametrize("shape,bs", [
-    ((2, 6, 2, 32, 32), 8),            # GQA g=3
-    ((1, 4, 4, 64, 16), 16),           # MHA, hd=16 (pads to 128)
+    ((2, 6, 2, 64, 64), 16),           # GQA g=3, hd=64: two tokens per row
+    ((1, 4, 4, 64, 16), 64),           # MHA, hd=16: eight tokens per row
     ((3, 8, 2, 128, 64), 32),          # wider pool
 ])
 def test_paged_kernel_vs_refs(shape, bs):
@@ -123,37 +134,34 @@ def test_paged_kernel_vs_refs(shape, bs):
     v = jax.random.normal(ks[2], (b, hkv, t, hd))
     lens = np.array([t - 3 * i for i in range(b)], np.int32)
     kp, vp, tables = _scatter_pages(k, v, lens, bs, n_pages)
-    tj, lj = jnp.asarray(tables), jnp.asarray(lens)
-    o = paged_decode_attention(q, kp, vp, tj, lj, scale=0.2,
-                               interpret=True)
-    r = paged_decode_attention_ref(q, kp, vp, tj, lj, scale=0.2)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r),
-                               atol=2e-5, rtol=2e-5)
+    kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    o = _pool_kernel(q.astype(jnp.bfloat16), kp, vp, tables, lens)
+    r = paged_decode_attention_ref(q, kp, vp, jnp.asarray(tables),
+                                   jnp.asarray(lens), scale=0.2)
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(r),
+                               atol=2e-2, rtol=2e-2)
     # the paged path must agree with the CONTIGUOUS oracle row by row
     for row in range(b):
         rc = decode_attention_ref(q[row:row + 1], k[row:row + 1],
                                   v[row:row + 1], int(lens[row]), scale=0.2)
-        np.testing.assert_allclose(np.asarray(o[row:row + 1]),
-                                   np.asarray(rc), atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(o[row:row + 1], np.float32),
+                                   np.asarray(rc), atol=2e-2, rtol=2e-2)
 
 
 def test_paged_kernel_ignores_sentinel_table_entries():
-    b, hq, hkv, t, hd, bs = 1, 2, 1, 32, 16, 8
+    b, hq, hkv, t, hd, bs = 1, 2, 1, 64, 64, 16
     n_pages = 8
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (b, hq, hd))
+    q = jax.random.normal(ks[0], (b, hq, hd)).astype(jnp.bfloat16)
     k = jax.random.normal(ks[1], (b, hkv, t, hd))
     v = jax.random.normal(ks[2], (b, hkv, t, hd))
-    lens = np.array([9], np.int32)                 # 2 of 4 pages valid
+    lens = np.array([17], np.int32)                # 2 of 4 pages valid
     kp, vp, tables = _scatter_pages(k, v, lens, bs, n_pages)
-    o1 = paged_decode_attention(q, kp, vp, jnp.asarray(tables),
-                                jnp.asarray(lens), scale=0.2,
-                                interpret=True)
+    kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    o1 = _pool_kernel(q, kp, vp, tables, lens)
     garbage = tables.copy()
     garbage[0, 2:] = [0, n_pages + 1000]           # valid-range AND huge ids
-    o2 = paged_decode_attention(q, kp, vp, jnp.asarray(garbage),
-                                jnp.asarray(lens), scale=0.2,
-                                interpret=True)
+    o2 = _pool_kernel(q, kp, vp, garbage, lens)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
 
@@ -221,14 +229,14 @@ ROW_PROMPTS = [[5, 9, 2, 7, 1, 4, 8, 3, 6, 2, 7, 7, 1, 9, 4, 2],
 
 
 def _canonical(leaf, hkv, bs=ROW_BS):
-    """A pool leaf (L, G, P, bs·(hkv/G)·w), read by its documented layout
-    (one row per layer, head group and page, token-major) as
+    """A pool leaf (L, G, P, *row), read by its documented layout (one row
+    of bs·(hkv/G)·w values per layer, head group and page, head-major) as
     (L, P, bs, hkv, w)."""
     leaf = np.asarray(leaf)
-    n_l, n_g, n_p, row = leaf.shape
-    w = row * n_g // (bs * hkv)
-    leaf = leaf.reshape(n_l, n_g, n_p, bs, hkv // n_g, w)
-    return leaf.transpose(0, 2, 3, 1, 4, 5).reshape(n_l, n_p, bs, hkv, w)
+    n_l, n_g, n_p = leaf.shape[:3]
+    w = leaf[0, 0, 0].size * n_g // (bs * hkv)
+    leaf = leaf.reshape(n_l, n_g, n_p, hkv // n_g, bs, w)
+    return leaf.transpose(0, 2, 4, 1, 3, 5).reshape(n_l, n_p, bs, hkv, w)
 
 
 def _row_view(leaf, table, hkv):
@@ -381,9 +389,9 @@ def test_tp2_pool_holds_whole_heads_per_device():
 
     def canonical(leaf):
         leaf = np.asarray(leaf)
-        n_l, n_g, n_p, row = leaf.shape
-        leaf = leaf.reshape(n_l, n_g, n_p, bs, hkv // n_g, -1)
-        return leaf.transpose(0, 2, 3, 1, 4, 5).reshape(n_l, n_p, -1)
+        n_l, n_g, n_p = leaf.shape[:3]
+        leaf = leaf.reshape(n_l, n_g, n_p, hkv // n_g, bs, -1)
+        return leaf.transpose(0, 2, 4, 1, 3, 5).reshape(n_l, n_p, -1)
 
     engines, reqs = {}, {}
     for tp in (1, 2):

@@ -1,4 +1,6 @@
-"""Described-chip compiles of the served kernels at smollm-360m widths.
+"""Described-chip compiles of the served kernels at smollm-360m widths
+(and the paged decode kernel at one device's slice of codeqwen1.5-7b at
+tp=4).
 
 Each test lowers a Pallas kernel with ``interpret=False`` for one chip of a
 described (not attached) TPU v5e and compiles it with the TPU compiler, so a
@@ -20,9 +22,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.inference.backends.bodies import make_step_bodies
-from repro.kernels.decode_attention.ops import paged_decode_attention
+from repro.kernels.decode_attention.layout import row_shape
+from repro.kernels.decode_attention.ops import (paged_decode_attention,
+                                                paged_decode_attention_quant)
 from repro.kernels.fused import residual_rmsnorm, rmsnorm_matmul
 from repro.kvcache import PagedKVCache
+from repro.layers import attention
 from repro.models import init_params
 
 CFG = get_config("smollm-360m")
@@ -51,32 +56,44 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _paged_args(sharding, page_dtype):
-    hq, hkv, hd = CFG.n_heads, CFG.n_kv_heads, CFG.hd
-    return (_sds((BATCH, hq, hd), jnp.bfloat16, sharding),
-            _sds((PAGES, BLOCK, hkv, hd), page_dtype, sharding),
-            _sds((PAGES, BLOCK, hkv, hd), page_dtype, sharding),
-            _sds((BATCH, MAX_LEN // BLOCK), jnp.int32, sharding),
-            _sds((BATCH,), jnp.int32, sharding))
-
-
 def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-def test_paged_decode_bf16_compiles(one_chip):
-    args = _paged_args(one_chip, jnp.bfloat16)
+# (rows, query heads, KV heads per device, head dim, pages, layers): the
+# smollm-chat cell, the same at 128 slots, and one device's slice of
+# codeqwen1.5-7b at tp=4 (8 slots x 2048 tokens, 32/4 heads of 128 over
+# four devices)
+POOL_KERNEL_SHAPES = {
+    "smollm-chat": (64, 15, 5, 64, 8192, 32),
+    "smollm-128-slots": (128, 15, 5, 64, 8192, 32),
+    "codeqwen-tp4-slice": (8, 8, 1, 128, 1024, 32),
+}
+
+
+@pytest.mark.parametrize("shape", list(POOL_KERNEL_SHAPES))
+def test_paged_decode_pool_kernel_compiles(one_chip, shape):
+    b, hq, hkv, hd, n_pages, n_layers = POOL_KERNEL_SHAPES[shape]
+    pool = _sds((n_layers, 1, n_pages) + row_shape(BLOCK, hkv, hd),
+                jnp.bfloat16, one_chip)
+    rows = _sds((b,), jnp.int32, one_chip)
     compiled = paged_decode_attention.lower(
-        *args, scale=CFG.hd ** -0.5, interpret=False).compile()
+        _sds((b, hq, hd), jnp.bfloat16, one_chip), pool, pool,
+        _sds((), jnp.int32, one_chip),
+        _sds((b, MAX_LEN // BLOCK), jnp.int32, one_chip), rows, rows,
+        scale=hd ** -0.5, kv_heads=hkv, interpret=False).compile()
     assert _has_kernel(compiled)
 
 
 def test_paged_decode_int8_compiles(one_chip):
-    args = _paged_args(one_chip, jnp.int8)
-    scales = _sds((PAGES, BLOCK, CFG.n_kv_heads), jnp.float32, one_chip)
-    compiled = paged_decode_attention.lower(
-        *args, scale=CFG.hd ** -0.5, interpret=False,
-        k_scale=scales, v_scale=scales).compile()
+    hq, hkv, hd = CFG.n_heads, CFG.n_kv_heads, CFG.hd
+    pages = _sds((PAGES, BLOCK, hkv, hd), jnp.int8, one_chip)
+    scales = _sds((PAGES, BLOCK, hkv), jnp.float32, one_chip)
+    compiled = paged_decode_attention_quant.lower(
+        _sds((BATCH, hq, hd), jnp.bfloat16, one_chip), pages, pages, scales,
+        scales, _sds((BATCH, MAX_LEN // BLOCK), jnp.int32, one_chip),
+        _sds((BATCH,), jnp.int32, one_chip), scale=hd ** -0.5,
+        interpret=False).compile()
     assert _has_kernel(compiled)
 
 
@@ -105,37 +122,55 @@ def test_residual_rmsnorm_compiles(one_chip):
 # 2048-token context, 512-token prefill chunks
 SLOTS, POOL_BLOCKS, CHUNK = 64, 8192, 512
 # what the compiler may keep beside arguments and output, per program
-TEMP_LIMIT = {"paged_decode": 0.4e9, "paged_prefill": 0.1e9}
+TEMP_LIMIT = {"paged_decode": 0.15e9, "paged_prefill": 0.1e9}
 
 _INSTR = re.compile(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
 
 
-def _pool_layer_moves(hlo: str, layer: tuple) -> list:
-    """Instructions that move one layer of a pool leaf: a ``copy`` whose
-    shape, size-1 axes aside, is ``layer`` (pages first, in any split of the
-    row), or a ``dynamic-update-slice`` writing such an operand.  The
-    gathered view of every row's table may hold as many elements, but it is
-    not pages-major."""
-    def is_layer(dims):
-        dims = [d for d in dims if d != 1]
-        return dims[:1] == [layer[0]] and math.prod(dims) == math.prod(layer)
+def _pool_moves(hlo: str, leaf: tuple) -> dict:
+    """Instructions that move a pool leaf, size-1 axes aside: ``"whole"``,
+    each ``copy`` of the whole stacked leaf; ``"layer"``, each ``copy`` of
+    one layer of it (pages first, in any split of the row) and each
+    ``dynamic-update-slice`` writing a layer or the whole leaf.  The
+    gathered view of every row's table may hold as many elements as a
+    layer, but it is not pages-major."""
+    leaf = [d for d in leaf if d != 1]
+    layer = leaf[1:]
 
-    shapes, found = {}, []
+    def kind(dims):
+        dims = [d for d in dims if d != 1]
+        if dims == leaf:
+            return "whole"
+        if dims[:1] == layer[:1] and math.prod(dims) == math.prod(layer):
+            return "layer"
+        return None
+
+    shapes, found = {}, {"whole": [], "layer": []}
     for name, shape, op, args in _INSTR.findall(hlo):
         shapes[name] = [int(d) for d in shape.split(",") if d]
-        if op == "copy" and is_layer(shapes[name]):
-            found.append(name)
+        if op == "copy" and kind(shapes[name]):
+            found[kind(shapes[name])].append(name)
         elif op == "dynamic-update-slice":
             update = re.findall(r"%([\w.-]+)", args)[1]
-            if is_layer(shapes.get(update, [])):
-                found.append(name)
+            if kind(shapes.get(update, [])):
+                found["layer"].append(name)
     return found
+
+
+def _table_gathers(hlo: str, elems: int) -> list:
+    """``gather`` instructions whose output holds at least ``elems``
+    elements: every row's whole table of pages."""
+    return [name for name, shape, op, _ in _INSTR.findall(hlo)
+            if op == "gather"
+            and math.prod(int(d) for d in shape.split(",") if d) >= elems]
 
 
 @pytest.fixture(scope="module")
 def served_steps(one_chip):
-    """The paged decode step and a full prefill chunk, compiled as
-    ``LocalBackend`` jits them, with the pool leaves' shape."""
+    """The paged decode step and a full prefill chunk compiled as
+    ``LocalBackend`` jits them, and again with the pool donated, with the
+    pool leaves' shape.  Pallas compiles for the described chip here, so
+    the program takes the kernel path it takes on the TPU."""
     kv = PagedKVCache(CFG, num_blocks=POOL_BLOCKS, block_size=BLOCK,
                       max_len=MAX_LEN)
     place = lambda tree: jax.tree.map(  # noqa: E731
@@ -147,20 +182,42 @@ def served_steps(one_chip):
     args = {"paged_decode": ((SLOTS, 1), (SLOTS,), (SLOTS, nb)),
             "paged_prefill": ((1, CHUNK), (nb,), ())}
     bodies = make_step_bodies(CFG)
-    compiled = {
-        name: jax.jit(getattr(bodies, name)).lower(
-            params, pages, *(_sds(s, i32, one_chip) for s in shapes)
-        ).compile()
-        for name, shapes in args.items()}
-    return compiled, jax.tree.leaves(pages)[0].shape
+    compiled = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(attention, "pallas_interpret", lambda: False)
+        for name, shapes in args.items():
+            for donate in ((), (1,)):
+                compiled[name, bool(donate)] = jax.jit(
+                    getattr(bodies, name), donate_argnums=donate).lower(
+                    params, pages, *(_sds(s, i32, one_chip) for s in shapes)
+                ).compile()
+    leaves = jax.tree.leaves(pages)
+    return compiled, leaves[0].shape, nb, len(leaves)
 
 
 @pytest.mark.parametrize("program", ["paged_decode", "paged_prefill"])
 def test_step_keeps_pool_in_place(served_steps, program):
-    """The layer loop reads and writes the pool where it lies: no copy of a
-    pool layer, and temporaries far below one layer of the pool."""
-    compiled, leaf = served_steps
-    c = compiled[program]
-    layer = tuple(d for d in leaf[1:] if d != 1)       # (pages, row)
-    assert _pool_layer_moves(c.as_text(), layer) == []
-    assert c.memory_analysis().temp_size_in_bytes <= TEMP_LIMIT[program]
+    """The layer loop reads and writes the pool where it lies.  As served
+    (undonated) the program copies each pool leaf once, whole, into its
+    output and moves no layer of it; with the pool donated not even that
+    copy is left.  Temporaries stay far below one layer of the pool."""
+    compiled, leaf, _, n_leaves = served_steps
+    served = _pool_moves(compiled[program, False].as_text(), leaf)
+    assert served["layer"] == []
+    assert len(served["whole"]) == n_leaves
+    donated = _pool_moves(compiled[program, True].as_text(), leaf)
+    assert donated == {"whole": [], "layer": []}
+    temp = compiled[program, False].memory_analysis().temp_size_in_bytes
+    assert temp <= TEMP_LIMIT[program]
+
+
+def test_decode_step_reads_pages_in_one_kernel(served_steps):
+    """The served decode step runs the paged kernel under the ``attn``
+    scope, where ``attention_roofline`` counts its time, and gathers no
+    row's whole table."""
+    compiled, leaf, nb, _ = served_steps
+    hlo = compiled["paged_decode", False].as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("/attn/" in line for line in kernels)
+    assert _table_gathers(hlo, SLOTS * nb * math.prod(leaf[3:])) == []
